@@ -10,8 +10,8 @@ reported (the raw data is evidence of a broken fence), but the schema
 carries the verdict so it can never silently become a headline.
 
 Peaks are per single chip, from public TPU spec sheets; the CPU entry
-is a deliberately generous bound so only transport-cache artifacts trip
-it, not honest readings on a fast host.
+is a deliberately generous bound so only caching artifacts trip it, not
+honest readings on a fast host.
 """
 from __future__ import annotations
 
@@ -61,17 +61,19 @@ EC_DECODE_K8M4 = {
 
 def chip_spec(platform: str, device_kind: str = "") -> Optional[Dict[str, float]]:
     """Resolve (platform, device_kind) to physical peaks, or None when
-    the backend is unknown (verdict becomes "unknown", never "ok")."""
+    the backend is not a TPU or CPU (verdict becomes "unknown", never
+    "ok").  A TPU whose kind is not in the table raises: its peaks are
+    unknown, and borrowing another generation's would make the roofline
+    verdict a guess."""
     kind = (device_kind or "").lower()
     for key, spec in CHIP_SPECS.items():
         if key != "cpu" and key in kind:
             return dict(spec)
     if platform == "cpu":
         return dict(CHIP_SPECS["cpu"])
-    if platform == "tpu" and not kind:
-        # unknown TPU generation: use the most permissive known peak so
-        # only physically impossible-anywhere numbers trip the flag
-        return dict(CHIP_SPECS["v6e"])
+    if platform == "tpu":
+        raise KeyError(f"TPU device_kind {device_kind!r} is not in "
+                       "roofline.CHIP_SPECS; add its published peaks")
     return None
 
 

@@ -10,9 +10,8 @@ column-sharded decode; the dispatch runtime wants the simplest layout
 that makes "more traffic" become "more chips".
 
 CPU smoke rides the virtual host platform
-(``XLA_FLAGS=--xla_force_host_platform_device_count=8``); discovery
-falls back to it exactly like :func:`ceph_tpu.parallel.mesh.make_mesh`
-when the default backend has fewer devices than requested.
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``) because it is
+then the default backend; discovery never swaps one backend for another.
 """
 from __future__ import annotations
 

@@ -7,7 +7,10 @@ The native library supplies:
   the benchmark baseline);
 - crc32c for chunk HashInfo.
 
-Builds on demand with the repo's Makefile (g++ -O3 -march=native).
+Builds on demand with the repo's Makefile (g++ -O3 -march=native), into
+a directory keyed by the host it runs on: a copy of the checkout moved
+to another machine builds its own library instead of loading one made
+for a different CPU (SIGILL).
 """
 from __future__ import annotations
 
@@ -29,15 +32,40 @@ from .crush.types import CrushMap
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _NATIVE_DIR = os.path.join(_ROOT, "native")
-_SO = os.path.join(_NATIVE_DIR, "libceph_tpu_native.so")
 
 _lock = DebugLock("native::load")
 _lib: Optional[ctypes.CDLL] = None
 
 
+def _host_key() -> str:
+    """What a -march=native build depends on: this machine (its name)
+    and its CPU (arch, model, feature flags)."""
+    import hashlib
+    import platform
+    import socket
+    h = hashlib.sha1(f"{platform.machine()}|{socket.gethostname()}".encode())
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("model name", "flags")):
+                    h.update(line.encode())
+                elif not line.strip():
+                    break               # the first CPU describes them all
+    except OSError:
+        pass
+    return h.hexdigest()[:16]
+
+
+def _lib_path() -> str:
+    return os.path.join(_NATIVE_DIR, "build", _host_key(),
+                        "libceph_tpu_native.so")
+
+
 def build_native() -> str:
-    subprocess.run(["make", "-s", "-C", _NATIVE_DIR], check=True)
-    return _SO
+    so = _lib_path()
+    subprocess.run(["make", "-s", "-C", _NATIVE_DIR, f"OUT={so}"],
+                   check=True)
+    return so
 
 
 def get_lib() -> ctypes.CDLL:
@@ -45,12 +73,13 @@ def get_lib() -> ctypes.CDLL:
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO) or (
-                os.path.getmtime(_SO) < max(
+        so = _lib_path()
+        if not os.path.exists(so) or (
+                os.path.getmtime(so) < max(
                     os.path.getmtime(os.path.join(_NATIVE_DIR, f))
                     for f in ("crush_mapper.cpp", "gf_rs.cpp"))):
             build_native()
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
         i64p = ctypes.POINTER(ctypes.c_int64)
         i32p = ctypes.POINTER(ctypes.c_int32)
         u32p = ctypes.POINTER(ctypes.c_uint32)

@@ -47,7 +47,6 @@ from ..crush.constants import (
     CRUSH_RULE_SET_CHOOSE_LOCAL_TRIES, CRUSH_RULE_SET_CHOOSE_TRIES,
     CRUSH_RULE_TAKE,
 )
-from ..arch import enable_x64
 from ..crush.ln import LL_NP, RH_LH_NP
 from ..crush.types import CrushMap
 
@@ -551,7 +550,7 @@ class DeviceCrushMapper:
 
     def map_batch(self, xs: np.ndarray, weight: np.ndarray):
         """Map all xs; returns (results [X, result_max] int32, counts [X])."""
-        with enable_x64():
+        with jax.enable_x64(True):
             xs = jnp.asarray(np.asarray(xs, dtype=np.uint32))
             w = jnp.asarray(np.asarray(weight, dtype=np.uint32))
             res, cnt = self._fn(xs, w)

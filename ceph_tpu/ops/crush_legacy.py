@@ -54,7 +54,6 @@ from ..crush.constants import (
     CRUSH_RULE_SET_CHOOSE_LOCAL_TRIES, CRUSH_RULE_SET_CHOOSE_TRIES,
     CRUSH_RULE_TAKE,
 )
-from ..arch import enable_x64
 from ..crush.mapper import crush_do_rule
 from ..crush.types import CrushMap
 from .crush_fast import UnsupportedRule, _G_EXACT, _layer_path_frontier
@@ -432,7 +431,7 @@ class LegacyFastRule:
                                                          np.ndarray]:
         xs = np.asarray(xs, dtype=np.uint32)
         w32 = np.asarray(weight, dtype=np.uint32)
-        with enable_x64():
+        with jax.enable_x64(True):
             out_d, cnt_d, res_d = self._resolve_jit(jnp.asarray(xs),
                                                     jnp.asarray(w32))
         out = np.asarray(out_d).astype(np.int32).copy()

@@ -31,20 +31,11 @@ def mesh_shape_for(n: int, max_shard: int = 2) -> Tuple[int, int]:
 def make_mesh(n_devices: Optional[int] = None,
               devices: Optional[Sequence] = None,
               max_shard: int = 2) -> Mesh:
+    """A (stripe, shard) mesh over *devices* (default: the default
+    backend's).  Asking for more devices than that backend has raises:
+    a mesh never moves off the chips onto virtual host devices."""
     if devices is None:
         devices = jax.devices()
-        if n_devices is not None and len(devices) < n_devices:
-            # single real chip but a bigger mesh requested: the virtual host
-            # platform carries --xla_force_host_platform_device_count devices
-            cpus = jax.devices("cpu")
-            if len(cpus) < n_devices:
-                try:
-                    # works when the cpu backend is not initialized yet
-                    jax.config.update("jax_num_cpu_devices", n_devices)
-                    cpus = jax.devices("cpu")
-                except Exception:
-                    pass
-            devices = cpus
     if n_devices is not None:
         if len(devices) < n_devices:
             raise ValueError(

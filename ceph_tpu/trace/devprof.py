@@ -212,7 +212,7 @@ class DevFlowProfiler:
     def account_host_copy(self, site: str, nbytes: int) -> None:
         """One host-side staging copy (pad, stack, message build) —
         counted toward the per-op copy ledger but not toward transfer
-        bytes (nothing crossed the PCIe/tunnel boundary)."""
+        bytes (nothing crossed the host/device boundary)."""
         nbytes = int(nbytes)
         if self._mirror:
             pc = devprof_perf_counters()

@@ -241,6 +241,10 @@ def test_seed_soak():
 
 
 # ---- elastic mesh membership ----------------------------------------------
+# loadflaky: the skew scoreboard samples per-chip host timings, so under
+# six busy test workers the re-add flush can place its real stripes
+# differently (failed once in PR 21's full run, passes alone)
+@pytest.mark.loadflaky
 def test_elastic_membership_retire_and_add_under_traffic():
     """ec_mesh_chips is injectargs-live: a retire mid-flight drains
     the dispatcher on the OLD mesh first (zero lost flushes, zero

@@ -351,6 +351,10 @@ def test_rateless_task_knob_and_dump_pane(rateless_conf):
     assert pane["n_parity"] == 1
 
 
+# loadflaky: which blocks the poll finds ready first is host timing; under
+# six busy test workers a parity block can beat a systematic one and
+# host_resolves moves (failed once in PR 21's full run, passes alone)
+@pytest.mark.loadflaky
 def test_wasted_blocks_account_the_bandwidth_price(rateless_conf):
     """Healthy flushes complete before consuming the parity blocks:
     wasted_blocks counts exactly the protection's bandwidth price and

@@ -23,6 +23,23 @@ def test_mesh_shape_factoring():
     assert mesh_shape_for(4, max_shard=4) == (1, 4)
 
 
+class _FakeTpu:
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+    def __init__(self, i):
+        self.id = i
+
+
+def test_make_mesh_raises_when_tpu_backend_is_short(monkeypatch):
+    """One chip, four asked for: make_mesh raises instead of swapping in
+    the virtual host devices (a mesh that silently left the chip would
+    make a four-chip run a CPU run)."""
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_FakeTpu(0)])
+    with pytest.raises(ValueError, match="need 4 devices, have 1"):
+        make_mesh(4)
+
+
 @pytest.mark.parametrize("n", [1, 2, 8])
 def test_sharded_encode_matches_host(n):
     k, m, s, c = 8, 4, 16, 512
