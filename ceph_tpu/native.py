@@ -8,9 +8,9 @@ The native library supplies:
 - crc32c for chunk HashInfo.
 
 Builds on demand with the repo's Makefile (g++ -O3 -march=native), into
-a directory keyed by the host it runs on: a copy of the checkout moved
-to another machine builds its own library instead of loading one made
-for a different CPU (SIGILL).
+a directory keyed by the CPU it runs on: a copy of the checkout moved
+to a machine with another CPU builds its own library instead of loading
+one made for a different CPU (SIGILL).
 """
 from __future__ import annotations
 
@@ -38,12 +38,11 @@ _lib: Optional[ctypes.CDLL] = None
 
 
 def _host_key() -> str:
-    """What a -march=native build depends on: this machine (its name)
-    and its CPU (arch, model, feature flags)."""
+    """What a -march=native build depends on: the CPU (arch, model,
+    feature flags), not the machine's name."""
     import hashlib
     import platform
-    import socket
-    h = hashlib.sha1(f"{platform.machine()}|{socket.gethostname()}".encode())
+    h = hashlib.sha1(platform.machine().encode())
     try:
         with open("/proc/cpuinfo") as f:
             for line in f:

@@ -13,8 +13,9 @@ One process, one run through the entry points a user calls:
 - served     ``MiniCluster`` of 16 OSDs with a k=8 m=4 tpu pool, default
              options: ``write_full`` 64 objects of 4 MiB (256 MiB), read
              them back, kill the OSD holding the most data shards, tick
-             until the mon sees it, read everything again (degraded reads,
-             decoded on the device).  All byte-exact.
+             until the mon sees it, read everything again (degraded reads).
+             All byte-exact; the writes must have encoded and the degraded
+             reads decoded on the device.
 - placement  100,000 PGs x 3 on the 1000-OSD straw2 map of the remap
              benchmark through ``OSDMapMapping.update()``, then 1% of the
              OSDs out and one remap epoch; both equal the native C++
@@ -100,13 +101,16 @@ class CompileMeter:
 
 
 def _device_site_calls(site: str) -> int:
-    """Host->device transfers the device-flow profiler saw at *site*."""
+    """Device calls at *site* that returned their result to the host
+    (the profiler counts the device->host leg only after the call)."""
     from ceph_tpu.trace.devprof import g_devprof
-    return g_devprof.dump()["sites"].get(site, {}).get("h2d_count", 0)
+    return g_devprof.dump()["sites"].get(site, {}).get("d2h_count", 0)
 
 
 def check_no_cpu_path(phase: str, fallbacks_at_start: int) -> None:
-    """Fail when anything in *phase* was quietly served off the device."""
+    """Fail when anything in *phase* was quietly served off the device.
+    (Mappings are checked where they are built: only the placement
+    phase builds an OSDMapMapping.)"""
     from ceph_tpu.fault import (fault_perf_counters, g_breakers,
                                 l_fault_cpu_fallbacks)
     fb = fault_perf_counters().get(l_fault_cpu_fallbacks)
@@ -212,10 +216,17 @@ def _kill_busiest_data_osd(c, n_objects: int, max_ticks: int = 20) -> int:
 def phase_served(n_osds: int = 16, n_objects: int = 64,
                  object_size: int = 4 << 20, pg_num: int = 64,
                  seed: int = 1) -> Dict:
+    """The cluster places PGs with the scalar host CRUSH of its OSDMap
+    (no OSDMapMapping runs here); its EC encodes and decodes must reach
+    the device."""
+    enc0 = _device_site_calls("gf_matmul.encode")
     t0 = time.perf_counter()
     c, cl, bodies = _fill_cluster(n_osds, n_objects, object_size, pg_num,
                                   seed)
     t_write = time.perf_counter()
+    encodes = _device_site_calls("gf_matmul.encode") - enc0
+    if encodes == 0:
+        raise SmokeFailure("served: no write encoded on the device")
     _read_all(cl, bodies, "healthy")
     t_read = time.perf_counter()
     victim = _kill_busiest_data_osd(c, n_objects)
@@ -228,7 +239,8 @@ def phase_served(n_osds: int = 16, n_objects: int = 64,
         raise SmokeFailure("served: no degraded read decoded on the device")
     return {"objects": n_objects, "object_mib": object_size / (1 << 20),
             "data_mib": bodies.nbytes >> 20, "osds": n_osds,
-            "victim": victim, "device_decodes": decodes,
+            "victim": victim, "device_encodes": encodes,
+            "device_decodes": decodes,
             "write_s": t_write - t0, "read_s": t_read - t_write,
             "degraded_read_s": t_deg - t_deg0}
 
