@@ -190,6 +190,11 @@ def osd_main(args) -> None:
     mon_names = [m for m in (args.mon_names or "mon").split(",") if m]
     daemon = osd_mod.OSD(net, args.id, mon_name=mon_names[0],
                          store=store, mon_names=mon_names)
+    # the daemon's clock is time.monotonic(), not the in-process
+    # fabric's tick count from 0: start it before the boot maps arrive,
+    # or every peer they mark up gets its last ping reply stamped at
+    # 0.0 and the first tick reports all of them failed
+    daemon.now = time.monotonic()
     # boot subscription: the mon's startup map pushes predate this
     # process's listener, so ask for the full history explicitly
     # (MonClient::sub_want("osdmap") at OSD::init) — from EVERY mon,
