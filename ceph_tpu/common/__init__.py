@@ -8,10 +8,7 @@ from .tracked_op import OpTracker, TrackedOp
 from .lockdep import (DebugLock, DebugRLock, LockOrderError,
                       lockdep_enable, lockdep_reset)
 from .dout import Dout, Log, dlog, get_log, register_config_observers
-from .kernel_trace import (
-    KernelTimer, annotate, g_kernel_timer, start_profiler_trace,
-    stop_profiler_trace,
-)
+from .kernel_trace import KernelTimer, g_kernel_timer
 
 __all__ = [
     "Option", "ConfigProxy", "OPT_INT", "OPT_STR", "OPT_FLOAT", "OPT_BOOL",
@@ -20,6 +17,5 @@ __all__ = [
     "DebugLock", "DebugRLock", "LockOrderError", "lockdep_enable",
     "lockdep_reset",
     "Dout", "Log", "dlog", "get_log", "register_config_observers",
-    "KernelTimer", "annotate", "g_kernel_timer", "start_profiler_trace",
-    "stop_profiler_trace",
+    "KernelTimer", "g_kernel_timer",
 ]

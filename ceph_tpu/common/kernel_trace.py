@@ -11,10 +11,9 @@ equivalents here:
   ``tracing_kernels`` or ``KernelTimer.enable()`` when diagnosing.
   Dumped over the admin socket ("kernel timings") next to perf
   counters — the "perf dump" of the device side.
-- ``annotate(name)``: a jax.profiler.TraceAnnotation passthrough so
-  framework phases show up named in a jax profiler trace (the
-  tracepoint provider analog); harmless no-op when the profiler is
-  inactive or jax is absent.
+- profiler spans: ``trace.span.Tracer.span(..., prof=<name>)`` puts
+  named host spans into a running jax profiler trace (the tracepoint
+  provider analog; docs/OBSERVABILITY.md has the catalog).
 - trace ids: already carried end-to-end by every message
   (msg/messages.py new_trace_id), surfaced in OpTracker events.
 """
@@ -133,40 +132,3 @@ class KernelTimer:
 
 
 g_kernel_timer = KernelTimer()
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region in a jax profiler trace (TraceAnnotation
-    passthrough).  Only the profiler plumbing is guarded — exceptions
-    from the annotated body always propagate unchanged."""
-    cm = None
-    try:
-        import jax.profiler
-        cm = jax.profiler.TraceAnnotation(name)
-    except Exception:
-        cm = None
-    if cm is None:
-        yield
-    else:
-        with cm:
-            yield
-
-
-def start_profiler_trace(log_dir: str) -> bool:
-    """Begin a jax profiler trace (view with tensorboard/xprof)."""
-    try:
-        import jax.profiler
-        jax.profiler.start_trace(log_dir)
-        return True
-    except Exception:
-        return False
-
-
-def stop_profiler_trace() -> bool:
-    try:
-        import jax.profiler
-        jax.profiler.stop_trace()
-        return True
-    except Exception:
-        return False

@@ -32,6 +32,7 @@ from .constants import (
 from .hash import crush_hash32_2, crush_hash32_3, crush_hash32_4
 from .ln import crush_ln
 from .types import Bucket, CrushMap, ChooseArg
+from ..trace.span import g_tracer
 
 
 def crush_find_rule(map: CrushMap, ruleset: int, type: int, size: int) -> int:
@@ -342,7 +343,15 @@ def _choose_indep(map: CrushMap, bucket: Bucket, weight, x: int,
 def crush_do_rule(map: CrushMap, ruleno: int, x: int, result_max: int,
                   weight: Sequence[int],
                   choose_args: Optional[List[ChooseArg]] = None) -> List[int]:
-    """Evaluate rule *ruleno* for input *x*; returns the result vector."""
+    """Evaluate rule *ruleno* for input *x*; returns the result vector.
+    One ``crush.scalar`` profiler span per call."""
+    with g_tracer.span(prof="crush.scalar"):
+        return _do_rule(map, ruleno, x, result_max, weight, choose_args)
+
+
+def _do_rule(map: CrushMap, ruleno: int, x: int, result_max: int,
+             weight: Sequence[int],
+             choose_args: Optional[List[ChooseArg]]) -> List[int]:
     if ruleno < 0 or ruleno >= map.max_rules or map.rules[ruleno] is None:
         return []
     rule = map.rules[ruleno]

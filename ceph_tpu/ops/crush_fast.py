@@ -65,6 +65,7 @@ from ..crush.ln import crush_ln_np
 from ..crush.mapper import crush_do_rule
 from ..crush.types import CrushMap
 from ..trace.devprof import g_devprof
+from ..trace.span import g_tracer
 from .crush_kernels import CompiledCrushMap, compile_map, hash32_2, hash32_3
 
 NONE = CRUSH_ITEM_NONE
@@ -1070,12 +1071,15 @@ class FastRule:
             # per-epoch fast path: fetch only the rows that changed since
             # the previous weight vector (plus residual guesses, which
             # must be re-verified) and patch the host mirror in place.
-            with g_devprof.stage("crush.map_batch"):
+            fetch = g_tracer.span(prof="crush.fetch", full=0)
+            with g_devprof.stage("crush.map_batch"), fetch:
                 flat = np.asarray(self._delta_jit(packed,
                                                   self._prev_packed,
                                                   cap))
+                n_changed = int(flat[0])
+                # rows patched into the host mirror (none on overflow)
+                fetch.set(rows=n_changed if n_changed <= cap else 0)
             g_devprof.account_d2h("crush.map_batch", flat.nbytes)
-            n_changed = int(flat[0])
             self._residual_frac = int(flat[1]) / X
             if n_changed <= cap:
                 out, counts = self._host_out, self._host_counts
@@ -1091,7 +1095,8 @@ class FastRule:
             # overflow: fall through to a full fetch (and grow the cap so
             # sustained churny workloads stop overflowing)
             self.delta_cap = min(2 * self.delta_cap, max(X, 1))
-        full = np.asarray(packed)
+        with g_tracer.span(prof="crush.fetch", rows=X, full=1):
+            full = np.asarray(packed)
         g_devprof.account_d2h("crush.map_batch", full.nbytes)
         out = full[:, :R].copy()
         counts = (full[:, R] & 0xFFFF).astype(np.int32)

@@ -36,6 +36,7 @@ from ..common.lockdep import DebugLock
 from ..gf.tables import expand_to_bitmatrix
 from ..gf.matrices import gf_invert_matrix
 from ..trace.devprof import g_devprof
+from ..trace.span import g_tracer
 
 
 @functools.lru_cache(maxsize=1)
@@ -146,6 +147,19 @@ class DeviceWordRSBackend:
         return out
 
 
+def _upload(data: np.ndarray) -> jnp.ndarray:
+    """The codec call's input batch onto the device."""
+    with g_tracer.span(prof="codec.h2d", bytes=data.nbytes):
+        return jnp.asarray(data)
+
+
+def _fetch(out: jnp.ndarray, nbytes: int) -> np.ndarray:
+    """The codec call's *nbytes* of output onto the host: the wait for
+    the kernel, then the copy."""
+    with g_tracer.span(prof="codec.fetch", bytes=nbytes):
+        return np.asarray(out)
+
+
 class DeviceRSBackend:
     """Device-side executor for one (k+m, k) systematic code."""
 
@@ -169,14 +183,16 @@ class DeviceRSBackend:
         batch crosses up, the coding chunks cross back.  Both legs are
         accounted per call-site by the device-flow profiler (counter
         bumps only — no sync is added; the ``jnp.asarray`` /
-        ``np.asarray`` pair was always the copy)."""
+        ``np.asarray`` pair was always the copy), and each leg is a
+        profiler span (``codec.h2d``, ``codec.fetch``)."""
         from ..common.kernel_trace import g_kernel_timer
         g_devprof.install_compile_listener()
         g_devprof.account_h2d("gf_matmul.encode", data.nbytes)
         with g_devprof.stage("gf_matmul.encode"):
             out = g_kernel_timer.timed(
-                "gf_encode", lambda:
-                np.asarray(self.encode_device(jnp.asarray(data))))
+                "gf_encode", lambda: _fetch(
+                    self.encode_device(_upload(data)),
+                    data.shape[0] * self.m * data.shape[2]))
         g_devprof.account_d2h("gf_matmul.encode", out.nbytes)
         return out
 
@@ -220,6 +236,8 @@ class DeviceRSBackend:
         g_devprof.install_compile_listener()
         g_devprof.account_h2d("gf_matmul.decode", survivors.nbytes)
         with g_devprof.stage("gf_matmul.decode"):
-            out = np.asarray(gf_bit_matmul(jnp.asarray(survivors), bits))
+            S, _k, C = survivors.shape
+            out = _fetch(gf_bit_matmul(_upload(survivors), bits),
+                         S * len(want_rows) * C)
         g_devprof.account_d2h("gf_matmul.decode", out.nbytes)
         return out

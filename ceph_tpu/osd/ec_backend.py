@@ -420,13 +420,7 @@ class ECBackend:
         signature-equal requests from other PGs otherwise."""
         t0 = time.perf_counter()
         want = set(range(self.n))
-        if g_tracer.enabled:
-            with g_tracer.span("ec_encode") as sp:
-                if sp is not None:      # enable() can race the check
-                    sp.tags["bytes"] = len(data)
-                shards = g_dispatcher.encode(self.sinfo, self.ec_impl,
-                                             data, want)
-        else:
+        with g_tracer.span("ec_encode", bytes=len(data)):
             shards = g_dispatcher.encode(self.sinfo, self.ec_impl, data,
                                          want)
         self.hist_encode.inc((time.perf_counter() - t0) * 1e6, len(data))
@@ -451,14 +445,7 @@ class ECBackend:
                               self.sinfo.get_chunk_size())
         t0 = time.perf_counter()
         try:
-            if g_tracer.enabled:
-                with g_tracer.span("ec_encode") as sp:
-                    if sp is not None:
-                        sp.tags["bytes"] = len(data)
-                        sp.tags["resident"] = True
-                    shards = encode_resident_shards(self.ec_impl,
-                                                    stripes)
-            else:
+            with g_tracer.span("ec_encode", bytes=len(data), resident=True):
                 shards = encode_resident_shards(self.ec_impl, stripes)
         except Exception:
             # any device-side surprise degrades to the classic path —
@@ -473,12 +460,7 @@ class ECBackend:
     def _decode_timed(self, nbytes: int, fn, *args):
         """Shared decode instrumentation (concat + shard-recovery)."""
         t0 = time.perf_counter()
-        if g_tracer.enabled:
-            with g_tracer.span("ec_decode") as sp:
-                if sp is not None:      # enable() can race the check
-                    sp.tags["bytes"] = nbytes
-                out = fn(*args)
-        else:
+        with g_tracer.span("ec_decode", bytes=nbytes):
             out = fn(*args)
         self.hist_decode.inc((time.perf_counter() - t0) * 1e6, nbytes)
         return out

@@ -761,12 +761,11 @@ class OSD(Dispatcher):
     # ---- shard sub-ops ----------------------------------------------------
     def _handle_sub_write(self, msg: MOSDECSubOpWrite) -> None:
         self.perf_counters.inc(L_OSD_SUBOP_W)
-        if g_tracer.enabled and msg.parent_span_id:
-            with g_tracer.span(f"sub_write:s{msg.shard}",
-                               daemon=self.name, trace_id=msg.trace_id,
-                               parent_id=msg.parent_span_id):
-                self._do_handle_sub_write(msg)
-        else:
+        ring = f"sub_write:s{msg.shard}" \
+            if g_tracer.enabled and msg.parent_span_id else None
+        with g_tracer.span(ring, daemon=self.name, trace_id=msg.trace_id,
+                           parent_id=msg.parent_span_id,
+                           prof="osd.sub_write", shard=msg.shard):
             self._do_handle_sub_write(msg)
 
     def _do_handle_sub_write(self, msg: MOSDECSubOpWrite) -> None:
@@ -847,12 +846,11 @@ class OSD(Dispatcher):
 
     def _handle_sub_read(self, msg: MOSDECSubOpRead) -> None:
         self.perf_counters.inc(L_OSD_SUBOP_R)
-        if g_tracer.enabled and msg.parent_span_id:
-            with g_tracer.span(f"sub_read:s{msg.shard}",
-                               daemon=self.name, trace_id=msg.trace_id,
-                               parent_id=msg.parent_span_id):
-                self._do_handle_sub_read(msg)
-        else:
+        ring = f"sub_read:s{msg.shard}" \
+            if g_tracer.enabled and msg.parent_span_id else None
+        with g_tracer.span(ring, daemon=self.name, trace_id=msg.trace_id,
+                           parent_id=msg.parent_span_id,
+                           prof="osd.sub_read", shard=msg.shard):
             self._do_handle_sub_read(msg)
 
     def _do_handle_sub_read(self, msg: MOSDECSubOpRead) -> None:

@@ -259,7 +259,8 @@ class OSDMapMapping:
         import time
         from ..trace import g_perf_histograms, g_tracer, latency_axes
         t0 = time.perf_counter()
-        with g_tracer.span("crush_map_update"):
+        with g_tracer.span("crush_map_update", prof="osdmap.update",
+                           pgs=sum(p.pg_num for p in osdmap.pools.values())):
             self.pools.clear()
             crush_fp = self._crush_fingerprint(osdmap) if self.use_device \
                 else None

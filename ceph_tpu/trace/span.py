@@ -14,16 +14,23 @@ returns ``None``; no span objects, no clock reads, and critically **no
 device syncs** are introduced anywhere.  Device drain time only appears
 as child spans when the kernel timer (``tracing_kernels``) is also on,
 because only then does a sync exist to measure.
+
+The same spans also reach the JAX profiler's trace, on the clock of its
+device planes: a span given a static ``prof`` name is emitted as a
+``jax.profiler.TraceAnnotation`` under that name, its keyword args as
+event stats, whenever a profiler session is running.  With the tracer
+disabled and no session, ``span()`` returns one shared no-op: no span
+object, no clock read.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
 import itertools
-import threading
+import sys
+import time
 
 from ..common.lockdep import DebugLock
-import time
 from collections import deque
 from typing import Deque, Dict, List, Optional
 
@@ -142,6 +149,10 @@ class Tracer:
     def __init__(self):
         self.enabled = False
         self.collector = SpanCollector()
+        # jax.profiler.TraceAnnotation, once something has imported it
+        # (no session can run before then, and tracing must not import
+        # JAX for every process that traces)
+        self._annotation = None
 
     def enable(self, on: bool = True) -> None:
         self.enabled = on
@@ -180,20 +191,37 @@ class Tracer:
         finally:
             _current.reset(token)
 
-    @contextlib.contextmanager
-    def span(self, name: str, daemon: str = "", trace_id: int = 0,
-             parent_id: int = 0):
-        """begin + activate + finish in one block."""
-        sp = self.begin(name, daemon, trace_id, parent_id)
-        if sp is None:
-            yield None
-            return
-        token = _current.set(sp)
-        try:
-            yield sp
-        finally:
-            _current.reset(token)
-            self.finish(sp)
+    def span(self, name: Optional[str] = None, daemon: str = "",
+             trace_id: int = 0, parent_id: int = 0, *,
+             prof: Optional[str] = None, **args):
+        """begin + activate + finish in one block, entered with ``with``
+        (which yields the ring's Span, or None).
+
+        Two sinks: the ring under *name* while the tracer is enabled
+        (*args* become its tags), and the profiler's trace under the
+        static *prof* name while a profiler session runs (*args* become
+        event stats).  A None *name* or *prof* leaves that sink out."""
+        on = prof is not None and self._profiling()
+        if not (self.enabled or on):
+            return _NO_SPAN
+        sp = None
+        if name is not None and self.enabled:
+            sp = self.begin(name, daemon, trace_id, parent_id)
+            if sp is not None and args:
+                sp.tags.update(args)
+        ann = self._annotation(prof, **args) if on else None
+        return _SpanScope(sp, ann) if sp is not None or ann is not None \
+            else _NO_SPAN
+
+    def _profiling(self) -> bool:
+        """Whether a JAX profiler session is running."""
+        ann = self._annotation
+        if ann is None:
+            mod = sys.modules.get("jax.profiler")
+            if mod is None:
+                return False
+            ann = self._annotation = mod.TraceAnnotation
+        return ann.is_enabled()
 
     def current(self) -> Optional[Span]:
         return _current.get()
@@ -216,5 +244,57 @@ class Tracer:
         cur = _current.get()
         return cur.trace_id if cur is not None else 0
 
+
+class _SpanScope:
+    """An open span: the ring's Span and/or the profiler's annotation."""
+
+    __slots__ = ("span", "ann", "_token")
+
+    def __init__(self, span: Optional[Span], ann):
+        self.span = span
+        self.ann = ann
+        self._token = None
+
+    def __enter__(self) -> Optional[Span]:
+        if self.ann is not None:
+            self.ann.__enter__()
+        if self.span is not None:
+            self._token = _current.set(self.span)
+        return self.span
+
+    def __exit__(self, *exc) -> bool:
+        sp = self.span
+        if sp is not None:
+            _current.reset(self._token)
+            if sp.end is None:
+                sp.end = time.monotonic()
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        return False
+
+    def set(self, **args) -> None:
+        """Args known only inside the span (rows fetched, ...)."""
+        if self.span is not None:
+            self.span.tags.update(args)
+        if self.ann is not None:
+            self.ann.set_metadata(**args)
+
+
+class _NoSpan:
+    """What ``span()`` returns with both sinks off: one shared object."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
 
 g_tracer = Tracer()

@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..trace.span import g_tracer
+
 _POLY = 0x82F63B78  # reflected CRC-32C polynomial
 
 
@@ -36,7 +38,13 @@ def crc32c_sw(data, crc: int = 0xFFFFFFFF) -> int:
 
 
 def crc32c(data, crc: int = 0xFFFFFFFF) -> int:
-    """Native when built, software otherwise; same bits either way."""
+    """Native when built, software otherwise; same bits either way.  One
+    ``crc32c`` profiler span per call."""
+    with g_tracer.span(prof="crc32c", bytes=len(data)):
+        return _crc32c(data, crc)
+
+
+def _crc32c(data, crc: int) -> int:
     try:
         from ..native import crc32c as native_crc32c, native_available
         if native_available():
