@@ -5,7 +5,8 @@ The native library supplies:
   serves as the threaded CPU batch baseline, the ParallelPGMapper analog);
 - GF(2^8) region encode (the isa-l ec_encode_data-class CPU path used as
   the benchmark baseline);
-- crc32c for chunk HashInfo.
+- crc32c for chunk HashInfo, on the CPU's crc instruction where it has
+  one (native/crc32c.cpp).
 
 Builds on demand with the repo's Makefile (g++ -O3 -march=native), into
 a directory keyed by the CPU it runs on: a copy of the checkout moved
@@ -15,6 +16,7 @@ one made for a different CPU (SIGILL).
 from __future__ import annotations
 
 import ctypes
+import glob
 import os
 import subprocess
 import threading
@@ -75,8 +77,8 @@ def get_lib() -> ctypes.CDLL:
         so = _lib_path()
         if not os.path.exists(so) or (
                 os.path.getmtime(so) < max(
-                    os.path.getmtime(os.path.join(_NATIVE_DIR, f))
-                    for f in ("crush_mapper.cpp", "gf_rs.cpp"))):
+                    os.path.getmtime(f) for f in glob.glob(
+                        os.path.join(_NATIVE_DIR, "*.cpp")))):
             build_native()
         lib = ctypes.CDLL(so)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -98,7 +100,10 @@ def get_lib() -> ctypes.CDLL:
             u8p, ctypes.c_int, ctypes.c_int, u8p, u8p, ctypes.c_int64]
         lib.gf_region_xor.argtypes = [u8p, u8p, u8p, ctypes.c_int64]
         lib.ceph_crc32c.restype = ctypes.c_uint32
-        lib.ceph_crc32c.argtypes = [ctypes.c_uint32, u8p, ctypes.c_int64]
+        lib.ceph_crc32c.argtypes = [
+            ctypes.c_uint32, ctypes.c_void_p, ctypes.c_int64]
+        lib.ceph_crc32c_impl.restype = ctypes.c_char_p
+        lib.ceph_crc32c_impl.argtypes = []
         lib.gf_mul_c.restype = ctypes.c_uint8
         lib.gf_mul_c.argtypes = [ctypes.c_uint8, ctypes.c_uint8]
         # inject the ln tables once
@@ -254,13 +259,29 @@ def native_rs_encode(matrix_rows: np.ndarray, data: np.ndarray) -> np.ndarray:
     return out
 
 
-def crc32c(data: bytes, crc: int = 0xFFFFFFFF) -> int:
+def _as_u8(data) -> np.ndarray:
+    """The bytes of *data* as a contiguous uint8 array, without a copy
+    where *data* already is one (bytes, bytearray, a contiguous
+    memoryview or uint8 array); an array of another dtype is cast, as
+    ``utils.crc32c.crc32c_sw`` casts it."""
+    if isinstance(data, np.ndarray):
+        return np.ascontiguousarray(data, dtype=np.uint8).reshape(-1)
+    try:
+        return np.frombuffer(data, dtype=np.uint8)
+    except (TypeError, ValueError):     # no contiguous buffer to share
+        return np.frombuffer(bytes(data), dtype=np.uint8)
+
+
+def crc32c(data, crc: int = 0xFFFFFFFF) -> int:
     """Ceph-convention crc32c: raw castagnoli update, no pre/post inversion
-    (reference include/crc32c.h); Ceph callers seed with -1."""
-    lib = get_lib()
-    buf = np.frombuffer(data, dtype=np.uint8) if not isinstance(
-        data, np.ndarray) else data
-    return int(lib.ceph_crc32c(
-        ctypes.c_uint32(crc),
-        buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        ctypes.c_int64(len(buf))))
+    (reference include/crc32c.h); Ceph callers seed with -1.  *data* is
+    any bytes-like object or array; it is read in place."""
+    buf = _as_u8(data)
+    lib = _lib if _lib is not None else get_lib()
+    return lib.ceph_crc32c(crc & 0xFFFFFFFF, buf.ctypes.data, buf.nbytes)
+
+
+def crc32c_impl() -> str:
+    """The crc32c the library was built with: ``"sse42"``, ``"armv8"``
+    (the CPU's crc instruction) or ``"table8"`` (slicing-by-8 tables)."""
+    return get_lib().ceph_crc32c_impl().decode()

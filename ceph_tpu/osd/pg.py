@@ -140,7 +140,7 @@ class ReplicatedBackend:
             if not msg.partial:
                 from ..utils.crc32c import crc32c
                 t.setattr(cid, ho, DIGEST_ATTR,
-                          struct.pack("<I", crc32c(bytes(msg.chunk))))
+                          struct.pack("<I", crc32c(msg.chunk)))
             else:
                 # unaligned overwrite: the whole-object digest no
                 # longer describes the bytes — invalidate, don't lie

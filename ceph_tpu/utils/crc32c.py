@@ -2,8 +2,9 @@
 
 Ceph computes raw crc32c updates with no pre/post inversion and seeds with
 -1 (reference include/crc32c.h, common/crc32c*.cc SSE4/table paths).  The
-native C++ path (ceph_tpu.native) is preferred; this table-driven fallback
-is bit-identical and keeps the dependency optional.
+native C++ path (ceph_tpu.native, on the CPU's crc instruction where it has
+one) is preferred; this table-driven fallback is bit-identical and keeps the
+dependency optional.
 """
 from __future__ import annotations
 
@@ -28,29 +29,35 @@ _TABLE = _build_table()
 
 
 def crc32c_sw(data, crc: int = 0xFFFFFFFF) -> int:
-    buf = np.frombuffer(bytes(data), dtype=np.uint8) \
-        if not isinstance(data, np.ndarray) else data.astype(np.uint8)
-    c = np.uint32(crc)
-    for b in buf.tobytes():
-        c = _TABLE[(int(c) ^ b) & 0xFF] ^ (int(c) >> 8)
-        c = np.uint32(c)
-    return int(c)
+    """The per-byte table loop in Python: the reference the native paths
+    are tested against, and the fallback where none is built."""
+    buf = data.astype(np.uint8).tobytes() if isinstance(data, np.ndarray) \
+        else bytes(data)
+    table = _TABLE.tolist()
+    c = crc & 0xFFFFFFFF
+    for b in buf:
+        c = table[(c ^ b) & 0xFF] ^ (c >> 8)
+    return c
+
+
+# (name, function) of the implementation this process uses, once chosen
+_impl = None
+
+
+def _choose():
+    global _impl
+    from .. import native
+    if native.native_available():
+        _impl = (native.crc32c_impl(), native.crc32c)
+    else:
+        _impl = ("python", crc32c_sw)
+    return _impl
 
 
 def crc32c(data, crc: int = 0xFFFFFFFF) -> int:
     """Native when built, software otherwise; same bits either way.  One
-    ``crc32c`` profiler span per call."""
-    with g_tracer.span(prof="crc32c", bytes=len(data)):
-        return _crc32c(data, crc)
-
-
-def _crc32c(data, crc: int) -> int:
-    try:
-        from ..native import crc32c as native_crc32c, native_available
-        if native_available():
-            return native_crc32c(
-                data if isinstance(data, (bytes, np.ndarray))
-                else bytes(data), crc)
-    except Exception:
-        pass
-    return crc32c_sw(data, crc)
+    ``crc32c`` profiler span per call; its ``impl`` arg names the path
+    (``"sse42"``, ``"armv8"``, ``"table8"`` or ``"python"``)."""
+    impl, fn = _impl or _choose()
+    with g_tracer.span(prof="crc32c", bytes=len(data), impl=impl):
+        return fn(data, crc)

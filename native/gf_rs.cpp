@@ -82,25 +82,4 @@ uint8_t gf_mul_c(uint8_t a, uint8_t b) {
   return g.mul[a][b];
 }
 
-// crc32c (Castagnoli), table-driven, in Ceph's convention: the raw table
-// update with NO pre/post bit inversion (reference include/crc32c.h
-// ceph_crc32c -> common/sctp_crc32.c update_crc32; golden vectors in
-// test/common/test_crc32c.cc, e.g. crc32c(0, "foo bar baz") = 4119623852).
-uint32_t ceph_crc32c(uint32_t crc, const uint8_t* data, int64_t n) {
-  static uint32_t table[256];
-  static bool init = false;
-  if (!init) {
-    for (uint32_t i = 0; i < 256; i++) {
-      uint32_t c = i;
-      for (int j = 0; j < 8; j++)
-        c = (c & 1) ? (c >> 1) ^ 0x82f63b78u : c >> 1;
-      table[i] = c;
-    }
-    init = true;
-  }
-  for (int64_t i = 0; i < n; i++)
-    crc = table[(crc ^ data[i]) & 0xff] ^ (crc >> 8);
-  return crc;
-}
-
 }  // extern "C"

@@ -20,7 +20,7 @@ from ceph_tpu.trace import span as span_mod
 CATALOG = {
     "codec.h2d": {"bytes"},
     "codec.fetch": {"bytes"},
-    "crc32c": {"bytes"},
+    "crc32c": {"bytes", "impl"},
     "crush.scalar": set(),
     "osd.sub_write": {"shard"},
     "osd.sub_read": {"shard"},
@@ -164,6 +164,14 @@ def test_crush_fetch_rows(traced):
     assert traced.events["osdmap.update"][0]["pgs"] == 64
 
 
+def test_crc32c_span_names_the_native_path(traced):
+    from ceph_tpu import native
+    impls = {e["impl"] for e in traced.events["crc32c"]}
+    assert impls == {native.crc32c_impl()}
+    assert impls <= {"sse42", "armv8", "table8"}
+    assert 100 in [e["bytes"] for e in traced.events["crc32c"]]
+
+
 def test_off_span_is_free(monkeypatch):
     """No session, tracer disabled: no Span, no clock, nothing kept."""
     def boom(*_a, **_k):
@@ -177,6 +185,10 @@ def test_off_span_is_free(monkeypatch):
     with a as sp:
         a.set(rows=3)
         assert sp is None
+    # the crc32c every caller goes through, its impl arg included
+    from ceph_tpu.utils.crc32c import crc32c
+    crc32c(b"\x01" * 4)
+    assert g_tracer.span(prof="crc32c", bytes=4, impl="sse42") is a
     assert g_tracer.collector.dump() == {}
 
 
