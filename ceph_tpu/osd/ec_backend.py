@@ -100,6 +100,7 @@ l_pipeline_stale_drops = 93004    # continuations dropped by an interval
 l_pipeline_errors = 93005         # ops whose encode future carried an
                                   # exception (client answered EIO)
 l_pipeline_subwrite_resends = 93006  # unacked sub-op writes resent
+l_pipeline_rmw_ops = 93007        # partial writes through the rmw path
 PIPELINE_LAST = 93010
 
 _pipeline_pc: Optional[PerfCounters] = None
@@ -131,6 +132,9 @@ def pipeline_perf_counters() -> PerfCounters:
             b.add_u64_counter(l_pipeline_subwrite_resends,
                               "subwrite_resends",
                               "unacked EC sub-op writes resent")
+            b.add_u64_counter(l_pipeline_rmw_ops, "rmw_ops",
+                              "partial EC writes spliced and re-encoded "
+                              "by the read-modify-write path")
             _pipeline_pc = b.create_perf_counters()
     return _pipeline_pc
 
@@ -150,19 +154,23 @@ def stash_pre_write_state(t: Transaction, store: MemStore, pg, oid: str,
     reference's append-only writes + rollback info in the PG log
     (ECTransaction.h rollback extents, ecbackend.rst:1-27)."""
     from .pg_log import encode_rollback, load_rollback, stage_rollback
-    prior = load_rollback(store, pg.meta_cid(), oid)
-    if prior is not None and prior[0] >= version:
-        # first-writer-wins per version: a replayed fan-out (resend whose
-        # log entry was dropped as stale, so the log dedup can't see it)
-        # would re-stash POST-apply state here and peering's rollback
-        # would then restore the wrong bytes — keep the original stash
-        return
-    exists = store.collection_exists(cid) and store.exists(cid, ho)
-    data = store.read(cid, ho) if exists else b""
-    attrs = dict(store.getattrs(cid, ho)) if exists else {}
-    mcid = pg.ensure_meta_collection(t)
-    stage_rollback(t, mcid, oid,
-                   encode_rollback(version, exists, data, attrs))
+    scope = g_tracer.span(prof="osd.rollback_stash", bytes=0)
+    with scope:
+        prior = load_rollback(store, pg.meta_cid(), oid)
+        if prior is not None and prior[0] >= version:
+            # first-writer-wins per version: a replayed fan-out (resend
+            # whose log entry was dropped as stale, so the log dedup
+            # can't see it) would re-stash POST-apply state here and
+            # peering's rollback would then restore the wrong bytes —
+            # keep the original stash
+            return
+        exists = store.collection_exists(cid) and store.exists(cid, ho)
+        data = store.read(cid, ho) if exists else b""
+        attrs = dict(store.getattrs(cid, ho)) if exists else {}
+        mcid = pg.ensure_meta_collection(t)
+        stage_rollback(t, mcid, oid,
+                       encode_rollback(version, exists, data, attrs))
+        scope.set(bytes=len(data))
 
 
 class ExtentCache:
@@ -825,25 +833,34 @@ class ECBackend:
             return
         cached = self.extent_cache.read(op.oid, a0, read_end - a0)
         if cached is not None:
-            self._rmw_have_old(op, a0, a1, cached)
+            self._rmw_have_old(op, a0, a1, cached, cache_hit=True)
             return
         c0 = self.sinfo.aligned_logical_offset_to_chunk_offset(a0)
         c1 = self.sinfo.aligned_logical_offset_to_chunk_offset(read_end)
         self._start_read(
             op.oid, c0, c1 - c0, False,
             lambda res, data, _size, _a: (
-                self._rmw_have_old(op, a0, a1, data) if res == 0 or
+                self._rmw_have_old(op, a0, a1, data,
+                                   preread=read_end - a0) if res == 0 or
                 (res == -2 and old_size == 0)
                 else (op.on_commit(res), self._op_done(op.oid))))
 
     def _rmw_have_old(self, op: RMWOp, a0: int, a1: int,
-                      old_bytes: bytes) -> None:
+                      old_bytes: bytes, preread: int = 0,
+                      cache_hit: bool = False) -> None:
         """Splice + re-encode the affected range in one device call, then
         fan chunk deltas (try_reads_to_commit, ECBackend.cc:1894).
         Runs from a read-reply callback — re-anchor the span and
-        ledger contexts."""
+        ledger contexts.  *preread*: the logical bytes read from the
+        shards for it (0 on an extent-cache hit or past the end)."""
+        pipeline_perf_counters().inc(l_pipeline_rmw_ops)
         with g_tracer.activate(op.parent_span), \
-                g_oplat.activate(op.ledger):
+                g_oplat.activate(op.ledger), \
+                g_tracer.span(prof="ec.rmw",
+                              stripes=(a1 - a0) //
+                              self.sinfo.get_stripe_width(),
+                              preread_bytes=preread,
+                              cache_hit=int(cache_hit)):
             buf = bytearray(a1 - a0)
             buf[:len(old_bytes)] = old_bytes
             rel = op.offset - a0
@@ -1073,23 +1090,23 @@ class ECBackend:
             if not msg.partial:
                 t.truncate(cid, ho, 0)
                 t.write(cid, ho, 0, msg.chunk)
-                body = msg.chunk
+                hinfo = self._shard_hinfo(msg.chunk)
             else:
-                existing = store.read(cid, ho) \
-                    if store.collection_exists(cid) and \
-                    store.exists(cid, ho) else b""
-                spliced = bytearray(max(len(existing),
-                                        msg.offset + len(msg.chunk)))
-                spliced[:len(existing)] = existing
-                spliced[msg.offset:msg.offset + len(msg.chunk)] = \
-                    msg.chunk
-                t.truncate(cid, ho, 0)
-                t.write(cid, ho, 0, bytes(spliced))
-                body = bytes(spliced)
-            hi = HashInfo(1)
-            hi.append(0, {0: np.frombuffer(body, dtype=np.uint8)})
-            hinfo = struct.pack("<QI", hi.total_chunk_size,
-                                hi.get_chunk_hash(0))
+                scope = g_tracer.span(prof="osd.sub_write.splice", bytes=0)
+                with scope:
+                    existing = store.read(cid, ho) \
+                        if store.collection_exists(cid) and \
+                        store.exists(cid, ho) else b""
+                    spliced = bytearray(max(len(existing),
+                                            msg.offset + len(msg.chunk)))
+                    spliced[:len(existing)] = existing
+                    spliced[msg.offset:msg.offset + len(msg.chunk)] = \
+                        msg.chunk
+                    t.truncate(cid, ho, 0)
+                    t.write(cid, ho, 0, bytes(spliced))
+                    body = bytes(spliced)
+                    hinfo = self._shard_hinfo(body)
+                    scope.set(bytes=len(body))
             memstore_device_perf_counters().inc(l_msd_crc_host)
         t.setattr(cid, ho, SIZE_ATTR, struct.pack("<Q", msg.at_version))
         self._apply_user_attrs(t, store, cid, ho, msg.xattrs)
@@ -1108,6 +1125,13 @@ class ECBackend:
             pg.data_received(msg.oid)
         return MOSDECSubOpWriteReply(tid=msg.tid, pgid=msg.pgid,
                                      shard=msg.shard, committed=True)
+
+    @staticmethod
+    def _shard_hinfo(body) -> bytes:
+        """The packed one-shard ``HashInfo`` (size, crc32c) of *body*."""
+        hi = HashInfo(1)
+        hi.append(0, {0: np.frombuffer(body, dtype=np.uint8)})
+        return struct.pack("<QI", hi.total_chunk_size, hi.get_chunk_hash(0))
 
     @staticmethod
     def _apply_user_attrs(t: Transaction, store: MemStore, cid: str, ho,

@@ -25,6 +25,8 @@ def cluster_and_text():
     cl = c.client("client.lint")
     assert cl.write_full("lint", "o", b"c" * 16000) == 0
     assert cl.read("lint", "o")[:1] == b"c"
+    # one partial overwrite so the read-modify-write counter moves
+    assert cl.write("lint", "o", b"w" * 100, 4000) == 0
     # one write through the MESH path so the per-chip occupancy
     # histogram registers and the mesh counters move — the lint below
     # then covers the mesh families like any other; skew probes run on
@@ -275,3 +277,16 @@ def test_slo_and_telemetry_options_documented():
     missing = [n for n in opts if n not in doc]
     assert not missing, \
         f"undocumented mgr_slo_/mgr_telemetry_ options: {missing}"
+
+
+def test_rmw_counter_is_exported(cluster_and_text):
+    """The read-modify-write op counter (the benchmark's
+    ``writes_not_rmw`` check reads it) renders with the ops it counted."""
+    from ceph_tpu.osd.ec_backend import (l_pipeline_rmw_ops,
+                                         pipeline_perf_counters)
+    _c, text = cluster_and_text
+    n = pipeline_perf_counters().get(l_pipeline_rmw_ops)
+    assert n >= 1
+    samples = [line for line in text.splitlines()
+               if line.startswith("ceph_daemon_pipeline_rmw_ops")]
+    assert samples and float(samples[0].rsplit(" ", 1)[1]) >= 1, samples

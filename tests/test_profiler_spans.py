@@ -1,8 +1,9 @@
 """Program spans in the JAX profiler's trace (``trace/span.py``).
 
 Inside one profiler session written to a temporary directory, the
-codec, crc32c, scalar CRUSH, the batched CRUSH fetch, ``update()`` and
-an EC write and read through a mini-cluster each leave their catalog
+codec, crc32c, scalar CRUSH, the batched CRUSH fetch, ``update()``, and
+an EC write, read and partial overwrite through a mini-cluster each
+leave their catalog
 span, with its args as event stats, in the ``.xplane.pb`` that
 ``ProfileData`` reads back.  With no session and the tracer disabled a
 span is one shared no-op: no ``Span``, no clock read, nothing recorded.
@@ -26,6 +27,9 @@ CATALOG = {
     "osd.sub_read": {"shard"},
     "osdmap.update": {"pgs"},
     "crush.fetch": {"rows", "full"},
+    "ec.rmw": {"stripes", "preread_bytes", "cache_hit"},
+    "osd.rollback_stash": {"bytes"},
+    "osd.sub_write.splice": {"bytes"},
 }
 
 
@@ -131,6 +135,8 @@ def traced(tmp_path_factory):
         mapping.update(osdmap)
         assert cl.write_full("spans", "obj", b"z" * 12288) == 0
         assert cl.read("spans", "obj") == b"z" * 12288
+        # a partial overwrite: the read-modify-write path
+        assert cl.write("spans", "obj", b"q" * 100, 5000) == 0
     finally:
         jax.profiler.stop_trace()
     np.testing.assert_array_equal(decoded[:, 0], data[:, 0])
@@ -170,6 +176,18 @@ def test_crc32c_span_names_the_native_path(traced):
     assert impls == {native.crc32c_impl()}
     assert impls <= {"sse42", "armv8", "table8"}
     assert 100 in [e["bytes"] for e in traced.events["crc32c"]]
+
+
+def test_rmw_span_args(traced):
+    """The overwrite re-encodes one stripe of 3 x 4096 bytes after
+    reading it from the shards; each shard stashes and splices its
+    4096-byte body (the full write before it stashed nothing)."""
+    assert traced.events["ec.rmw"] == [
+        {"stripes": 1, "preread_bytes": 12288, "cache_hit": 0}]
+    splice = [e["bytes"] for e in traced.events["osd.sub_write.splice"]]
+    assert splice == [4096] * 5
+    stash = sorted(e["bytes"] for e in traced.events["osd.rollback_stash"])
+    assert stash == [0] * 5 + [4096] * 5
 
 
 def test_off_span_is_free(monkeypatch):
