@@ -344,8 +344,8 @@ def crush_do_rule(map: CrushMap, ruleno: int, x: int, result_max: int,
                   weight: Sequence[int],
                   choose_args: Optional[List[ChooseArg]] = None) -> List[int]:
     """Evaluate rule *ruleno* for input *x*; returns the result vector.
-    One ``crush.scalar`` profiler span per call."""
-    with g_tracer.span(prof="crush.scalar"):
+    One ``crush.scalar`` profiler span per call, ``impl="python"``."""
+    with g_tracer.span(prof="crush.scalar", impl="python"):
         return _do_rule(map, ruleno, x, result_max, weight, choose_args)
 
 

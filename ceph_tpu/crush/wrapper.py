@@ -17,8 +17,29 @@ from .constants import (
     CRUSH_RULE_TAKE, PG_POOL_TYPE_REPLICATED,
 )
 from . import builder
-from .mapper import crush_do_rule, crush_find_rule
+from .mapper import _do_rule, crush_find_rule
 from .types import Bucket, ChooseArg, CrushMap, Rule, RuleStep
+from ..trace.span import g_tracer
+
+
+def _native_do_rule(m: CrushMap, ruleno: int, x: int, result_max: int,
+                    weight: Sequence[int],
+                    choose_args: Optional[List[ChooseArg]]
+                    ) -> Optional[List[int]]:
+    """The rule evaluated on the C++ engine, or None where it cannot
+    answer: no library, a map it cannot take (a malformed
+    ``choose_args`` fails ``serialize_map``), or a choose-tries
+    histogram armed (``crushtool --show-choose-tries``), which only the
+    Python interpreter fills."""
+    from .. import native
+    if getattr(m, "choose_tries", None) is not None \
+            or not native.native_available():
+        return None
+    try:
+        return native.NativeCrushMapper(m, choose_args).do_rule(
+            ruleno, x, result_max, weight)
+    except (ValueError, RuntimeError):
+        return None
 
 
 class CrushWrapper:
@@ -524,10 +545,23 @@ class CrushWrapper:
     def do_rule(self, ruleno: int, x: int, maxout: int,
                 weight: Sequence[int],
                 choose_args_index: Optional[int] = None) -> List[int]:
+        """The per-PG lookup: on the C++ engine where it can answer,
+        else on the Python interpreter (``mapper.crush_do_rule``); the
+        same placement either way.  One ``crush.scalar`` profiler span
+        per evaluation; its ``impl`` arg names the engine that ran."""
+        m = self.crush
+        if ruleno < 0 or ruleno >= m.max_rules or m.rules[ruleno] is None:
+            return []
         ca = None
         if choose_args_index is not None:
-            ca = self.crush.choose_args.get(choose_args_index)
-        return crush_do_rule(self.crush, ruleno, x, maxout, weight, ca)
+            ca = m.choose_args.get(choose_args_index)
+        scope = g_tracer.span(prof="crush.scalar")
+        with scope:
+            out = _native_do_rule(m, ruleno, x, maxout, weight, ca)
+            scope.set(impl="python" if out is None else "native")
+            if out is None:
+                out = _do_rule(m, ruleno, x, maxout, weight, ca)
+            return out
 
     # ---- introspection ----------------------------------------------------
     def get_children(self, id: int) -> List[int]:

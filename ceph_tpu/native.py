@@ -37,6 +37,9 @@ _NATIVE_DIR = os.path.join(_ROOT, "native")
 
 _lock = DebugLock("native::load")
 _lib: Optional[ctypes.CDLL] = None
+# set once a load has failed: a host with no toolchain is asked once,
+# not on every per-PG lookup
+_load_failed = False
 
 
 def _host_key() -> str:
@@ -71,6 +74,8 @@ def build_native() -> str:
 
 def get_lib() -> ctypes.CDLL:
     global _lib
+    if _lib is not None:
+        return _lib
     with _lock:
         if _lib is not None:
             return _lib
@@ -118,10 +123,16 @@ def get_lib() -> ctypes.CDLL:
 
 
 def native_available() -> bool:
+    global _load_failed
+    if _lib is not None:
+        return True
+    if _load_failed:
+        return False
     try:
         get_lib()
         return True
     except Exception:
+        _load_failed = True
         return False
 
 

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from ceph_tpu.crush import CrushWrapper, CRUSH_BUCKET_STRAW2
+from ceph_tpu.crush.mapper import crush_do_rule
 from ceph_tpu.osdmap import OSDMap, pg_t
 from ceph_tpu.osdmap.balancer import calc_weight_set
 from ceph_tpu.osdmap.types import pg_pool_t, TYPE_REPLICATED
@@ -235,7 +236,7 @@ def test_native_mapper_choose_args_bit_exact():
     w = [0x10000] * (m.max_osd - 1) + [0]
     out, lens = nm.do_rule_batch(rno, list(range(300)), 3, w)
     for x in range(300):
-        expect = cw.do_rule(rno, x, 3, list(w), choose_args_index=pid)
+        expect = crush_do_rule(cw.crush, rno, x, 3, list(w), args)
         assert list(out[x][:lens[x]]) == expect, x
 
 

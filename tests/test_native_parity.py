@@ -18,6 +18,7 @@ from ceph_tpu.crush import (
     CRUSH_BUCKET_STRAW2, CRUSH_BUCKET_TREE, CRUSH_BUCKET_UNIFORM,
     PG_POOL_TYPE_ERASURE,
 )
+from ceph_tpu.crush.mapper import crush_do_rule
 from ceph_tpu.ec.rs_codec import MatrixRSCodec
 from ceph_tpu.gf.matrices import gf_gen_rs_matrix
 from ceph_tpu.gf.tables import gf_mul
@@ -190,7 +191,7 @@ def test_mapper_parity_random_maps(mode, rule_type, algs):
             weight[int(i)] = int(rng.integers(0, 2)) * 0x8000
         nrep = 3
         for x in range(500):
-            py = cw.do_rule(rno, x, nrep, weight)
+            py = crush_do_rule(cw.crush, rno, x, nrep, weight)
             cc = nm.do_rule(rno, x, nrep, weight)
             assert py == cc, (trial, x, py, cc)
 
@@ -204,4 +205,5 @@ def test_mapper_parity_batch():
     weight = [0x10000] * 24
     out, lens = nm.do_rule_batch(rno, list(range(1000)), 4, weight)
     for x in (0, 17, 500, 999):
-        assert cw.do_rule(rno, x, 4, weight) == out[x, :lens[x]].tolist()
+        assert crush_do_rule(cw.crush, rno, x, 4, weight) == \
+            out[x, :lens[x]].tolist()

@@ -22,7 +22,7 @@ CATALOG = {
     "codec.h2d": {"bytes"},
     "codec.fetch": {"bytes"},
     "crc32c": {"bytes", "impl"},
-    "crush.scalar": set(),
+    "crush.scalar": {"impl"},
     "osd.sub_write": {"shard"},
     "osd.sub_read": {"shard"},
     "osdmap.update": {"pgs"},
@@ -127,6 +127,7 @@ def traced(tmp_path_factory):
         decoded = dev.decode_data(survivors, (1, 2, 3, 4), (0,))
         crc32c(b"\x01" * 100)
         crush_do_rule(cw.crush, rno, 7, 3, weight)
+        cw.do_rule(rno, 7, 3, weight)
         fresh = compile_fast_rule(cw.crush, rno, 3)
         fresh.map_batch(xs, weight)             # full fetch
         fresh.map_batch(xs, out_w)              # delta fetch
@@ -176,6 +177,16 @@ def test_crc32c_span_names_the_native_path(traced):
     assert impls == {native.crc32c_impl()}
     assert impls <= {"sse42", "armv8", "table8"}
     assert 100 in [e["bytes"] for e in traced.events["crc32c"]]
+
+
+def test_crush_scalar_span_names_the_engine(traced):
+    """The interpreter called directly says ``python``; the per-PG
+    lookup (``CrushWrapper.do_rule``, and the client's placement of
+    each op through it) runs on the C++ engine and says ``native``."""
+    impls = [e["impl"] for e in traced.events["crush.scalar"]]
+    assert impls.count("python") == 1
+    assert impls.count("native") >= 3       # do_rule; write, read, overwrite
+    assert set(impls) == {"python", "native"}
 
 
 def test_rmw_span_args(traced):
