@@ -3,8 +3,9 @@
 Bit-exact with the reference (src/crush/hash.c): the Jenkins mix with seed
 1315423911 and pad constants 231232/1232, in 1..5-argument variants.  The
 scalar versions use masked Python ints (the oracle); the numpy versions are
-vectorized for the batch host mapper; the device versions live in
-ceph_tpu/ops/crush_kernels.py and share the same structure in uint32 lanes.
+vectorized for the batch host mapper; the device versions, which
+ops/crush_fast.py uses, live in ceph_tpu/ops/crush_kernels.py and share the
+same structure in uint32 lanes.
 """
 from __future__ import annotations
 

@@ -12,7 +12,7 @@ import numpy as np
 
 from ._ln_table_data import RH_LH_TBL, LL_TBL
 
-# numpy copies for the vectorized host mapper / device upload
+# numpy copies for crush_ln_np and the native library's tables
 RH_LH_NP = np.array(RH_LH_TBL, dtype=np.uint64)
 LL_NP = np.array(LL_TBL, dtype=np.uint64)
 

@@ -8,10 +8,10 @@ truncated division), the same r' = r + ftotal retry sequences, collision and
 out-rejection logic, and the same firstn/indep output conventions
 (CRUSH_ITEM_NONE padding for indep).
 
-This is the oracle the vmapped device mapper (ceph_tpu/ops/crush_kernels.py)
-is tested against.  It is deliberately written for clarity+exactness, not
-speed; batch host mapping uses numpy vectorization at the OSDMap layer and
-the TPU path for scale.
+This is the oracle the device mapper (ceph_tpu/ops/crush_fast.py) and the
+C++ mapper (native/crush_mapper.cpp) are tested against, and the engine of
+last resort behind crush/wrapper.py's native-or-interpreter choice.  It is
+deliberately written for clarity+exactness, not speed.
 """
 from __future__ import annotations
 

@@ -7,8 +7,8 @@ object math like the reference's `vector<float>`), bad-mapping
 detection, adjustable device weights (--weight), the choose-tries
 histogram (mapper profile), and the --output-csv data files.  The
 sweep itself runs through the batch mapper stack (device fast path →
-host), so the harness doubles as the device/host parity oracle the
-reference uses golden files for.
+C++ mapper → interpreter), so the harness doubles as the device/host
+parity oracle the reference uses golden files for.
 """
 from __future__ import annotations
 
@@ -19,8 +19,7 @@ from typing import Dict, List, Optional, TextIO
 import numpy as np
 
 from .constants import CRUSH_ITEM_NONE
-from .mapper import crush_do_rule
-from .wrapper import CrushWrapper
+from .wrapper import CrushWrapper, do_rule_batch
 
 
 class CrushTester:
@@ -146,18 +145,12 @@ class CrushTester:
             try:
                 from ..ops.crush_fast import compile_fast_rule
                 fr = compile_fast_rule(self.crush.crush, ruleno, numrep)
-                res, cnt = fr.map_batch(np.asarray(xs, dtype=np.uint32),
-                                        np.asarray(weight, dtype=np.uint32))
-                return res, cnt
-            except Exception:
+                return fr.map_batch(np.asarray(xs, dtype=np.uint32),
+                                    np.asarray(weight, dtype=np.uint32))
+            except (ValueError, ImportError):
                 pass
-        out = np.full((len(xs), numrep), CRUSH_ITEM_NONE, dtype=np.int32)
-        cnt = np.zeros(len(xs), dtype=np.int32)
-        for i, x in enumerate(xs):
-            r = crush_do_rule(self.crush.crush, ruleno, int(x), numrep,
-                              weight)
-            out[i, :len(r)] = r
-            cnt[i] = len(r)
+        out, cnt, _engine = do_rule_batch(self.crush.crush, ruleno, xs,
+                                          numrep, weight)
         return out, cnt
 
     def _max_affected_by_rule(self, ruleno: int) -> int:

@@ -4,42 +4,79 @@ Semantics follow the reference C++ façade (src/crush/CrushWrapper.{h,cc}):
 type/bucket/rule name registries, hierarchy construction, add_simple_rule
 ("firstn"/"indep" step templates incl. the indep SET_CHOOSELEAF_TRIES=5 /
 SET_CHOOSE_TRIES=100 preamble, CrushWrapper.cc add_simple_rule_at), tunable
-profiles, per-map choose_args, and the batch do_rule entry used by OSDMap.
+profiles, per-map choose_args, and the one place that chooses between the
+C++ engine and the Python interpreter (``native_mapper``, ``do_rule`` per
+x, ``do_rule_batch`` for the batch callers).
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from .constants import (
-    CRUSH_RULE_CHOOSELEAF_FIRSTN, CRUSH_RULE_CHOOSELEAF_INDEP,
-    CRUSH_RULE_CHOOSE_FIRSTN, CRUSH_RULE_CHOOSE_INDEP, CRUSH_RULE_EMIT,
+    CRUSH_ITEM_NONE, CRUSH_RULE_CHOOSELEAF_FIRSTN,
+    CRUSH_RULE_CHOOSELEAF_INDEP, CRUSH_RULE_CHOOSE_FIRSTN,
+    CRUSH_RULE_CHOOSE_INDEP, CRUSH_RULE_EMIT,
     CRUSH_RULE_SET_CHOOSELEAF_TRIES, CRUSH_RULE_SET_CHOOSE_TRIES,
     CRUSH_RULE_TAKE, PG_POOL_TYPE_REPLICATED,
 )
 from . import builder
-from .mapper import _do_rule, crush_find_rule
+from .mapper import _do_rule, crush_do_rule, crush_find_rule
 from .types import Bucket, ChooseArg, CrushMap, Rule, RuleStep
 from ..trace.span import g_tracer
 
 
-def _native_do_rule(m: CrushMap, ruleno: int, x: int, result_max: int,
-                    weight: Sequence[int],
-                    choose_args: Optional[List[ChooseArg]]
-                    ) -> Optional[List[int]]:
-    """The rule evaluated on the C++ engine, or None where it cannot
-    answer: no library, a map it cannot take (a malformed
-    ``choose_args`` fails ``serialize_map``), or a choose-tries
-    histogram armed (``crushtool --show-choose-tries``), which only the
-    Python interpreter fills."""
+# what the binding raises for a map it refuses: serialize_map's
+# ValueError (a malformed ``choose_args``), the C++ parser's RuntimeError
+_REFUSED = (ValueError, RuntimeError)
+
+
+def native_mapper(m: CrushMap, choose_args: Optional[List[ChooseArg]] = None,
+                  mapper=None):
+    """The C++ engine for *m*, or None where the interpreter must answer:
+    no library, a choose-tries histogram armed (``crushtool
+    --show-choose-tries``, which only the interpreter fills), or a map
+    the binding refuses.  *mapper* is one the caller keeps loaded with
+    *m*; it is returned under the same guard."""
     from .. import native
     if getattr(m, "choose_tries", None) is not None \
             or not native.native_available():
         return None
+    if mapper is not None:
+        return mapper
     try:
-        return native.NativeCrushMapper(m, choose_args).do_rule(
-            ruleno, x, result_max, weight)
-    except (ValueError, RuntimeError):
+        return native.NativeCrushMapper(m, choose_args)
+    except _REFUSED:
         return None
+
+
+def do_rule_batch(m: CrushMap, ruleno: int, xs, result_max: int,
+                  weight: Sequence[int],
+                  choose_args: Optional[List[ChooseArg]] = None,
+                  mapper=None) -> Tuple[np.ndarray, np.ndarray, str]:
+    """Rule *ruleno* for every x of *xs*: on the C++ engine where it can
+    answer (``native_mapper``), else on the Python interpreter; the same
+    placement either way.  Returns (rows ``(len(xs), result_max)`` int64,
+    CRUSH_ITEM_NONE-padded; counts ``(len(xs),)``; the engine that
+    answered, ``"native"`` or ``"python"``).  The interpreter emits one
+    ``crush.scalar`` span per x (``crush_do_rule``); the C++ batch none."""
+    xs = np.asarray(xs, dtype=np.int64)
+    nm = native_mapper(m, choose_args, mapper)
+    if nm is not None:
+        try:
+            rows, counts = nm.do_rule_batch(ruleno, xs, result_max, weight)
+            return rows, counts, "native"
+        except _REFUSED:
+            pass
+    rows = np.full((len(xs), result_max), CRUSH_ITEM_NONE, dtype=np.int64)
+    counts = np.zeros(len(xs), dtype=np.int32)
+    wl = np.asarray(weight, dtype=np.uint32).tolist()
+    for i, x in enumerate(xs.tolist()):
+        r = crush_do_rule(m, ruleno, x, result_max, wl, choose_args)
+        rows[i, :len(r)] = r
+        counts[i] = len(r)
+    return rows, counts, "python"
 
 
 class CrushWrapper:
@@ -557,11 +594,16 @@ class CrushWrapper:
             ca = m.choose_args.get(choose_args_index)
         scope = g_tracer.span(prof="crush.scalar")
         with scope:
-            out = _native_do_rule(m, ruleno, x, maxout, weight, ca)
-            scope.set(impl="python" if out is None else "native")
-            if out is None:
-                out = _do_rule(m, ruleno, x, maxout, weight, ca)
-            return out
+            nm = native_mapper(m, ca)
+            if nm is not None:
+                try:
+                    out = nm.do_rule(ruleno, x, maxout, weight)
+                    scope.set(impl="native")
+                    return out
+                except _REFUSED:
+                    pass
+            scope.set(impl="python")
+            return _do_rule(m, ruleno, x, maxout, weight, ca)
 
     # ---- introspection ----------------------------------------------------
     def get_children(self, id: int) -> List[int]:

@@ -1,9 +1,8 @@
-"""Candidate-table CRUSH mapper — the loop-free device fast path.
+"""Candidate-table CRUSH mapper — the device CRUSH mapper.
 
-The generic loop kernel (crush_kernels.py) replicates crush_do_rule's
-data-dependent retry loops directly; under vmap every lane pays for the
-worst lane, which measures ~100x off the <50 ms target.  This module uses
-the TPU-native formulation instead:
+crush_do_rule's retry loops are data-dependent: evaluated directly under
+vmap, every lane pays for the worst lane.  This module uses a loop-free
+formulation instead:
 
 1. *Candidate tables* (the FLOPs): for every x and every retry index r the
    rule could consume, evaluate the full descent (root → failure domain →
@@ -32,15 +31,17 @@ the TPU-native formulation instead:
    reweight) re-runs only this phase.
 
 3. *Residuals* (exactness escape hatch): flagged lanes — zero on
-   integer-table maps, well under 1% otherwise — are recomputed with the
-   bit-exact native C++ batch evaluator (Python interpreter fallback), so
-   the combined result equals crush_do_rule on every input.
+   integer-table maps, well under 1% otherwise — are recomputed by
+   crush/wrapper.py's do_rule_batch (the bit-exact C++ batch evaluator,
+   else the Python interpreter), so the combined result equals
+   crush_do_rule on every input.
 
 Scope: straw2 maps, layered hierarchies (every descent path from the take
 root crosses the same bucket types at the same depths), jewel-style
-tunables (stable chooseleaf for firstn; local tries 0), and single-choose
-rules of the add_simple_rule shape.  Everything else falls back to the
-loop kernel or the host.
+tunables (stable chooseleaf for firstn; local tries 0), and rules of one
+take and at most two choose steps.  Everything else raises UnsupportedRule
+(a ValueError); OSDMapMapping and CrushTester then map through
+crush/wrapper.py's do_rule_batch.
 """
 from __future__ import annotations
 
@@ -62,8 +63,8 @@ from ..crush.constants import (
     CRUSH_RULE_TAKE,
 )
 from ..crush.ln import crush_ln_np
-from ..crush.mapper import crush_do_rule
 from ..crush.types import CrushMap
+from ..crush.wrapper import do_rule_batch, native_mapper
 from ..trace.devprof import g_devprof
 from ..trace.span import g_tracer
 from .crush_kernels import CompiledCrushMap, compile_map, hash32_2, hash32_3
@@ -348,11 +349,11 @@ class FastRule:
         self._build_quotient_tables()
         # non-quotient-table levels (non-uniform weights, choose_args,
         # small w) draw EXACTLY with the u64 table-gather + divide —
-        # the same div64_s64 the loop kernel runs on device — instead
-        # of the f32 approximation, killing the residual-replay tail.
-        # One-time cost in the cached candidate phase; the per-epoch
-        # resolve stays 32-bit.  Opt out (or auto-fallback when a
-        # backend can't lower u64 divide) -> f32 + risk flags.
+        # mapper.c's div64_s64 — instead of the f32 approximation,
+        # killing the residual-replay tail.  One-time cost in the
+        # cached candidate phase; the per-epoch resolve stays 32-bit.
+        # Opt out (or auto-fallback when a backend can't lower u64
+        # divide) -> f32 + risk flags.
         if exact64 is None:
             exact64 = os.environ.get("CEPH_TPU_CRUSH_EXACT64",
                                      "1") != "0"
@@ -455,7 +456,7 @@ class FastRule:
         w = C.weights[jnp.minimum(pos, C.npos - 1), bidx]  # (N, S) u32
         u = hash32_3(x[:, None], ids, r[:, None]) & jnp.uint32(0xFFFF)
         # constant converted at use site so the int64 table survives
-        # only inside the x64 trace (crush_kernels.py's convention)
+        # only inside the x64 trace
         g = jnp.asarray(_G_EXACT)[u.astype(jnp.int32)]
         valid = (C.lane[None, :] < C.sizes[bidx][:, None]) & (w > 0)
         q = jnp.where(valid,
@@ -955,31 +956,16 @@ class FastRule:
 
     def _replay_exact(self, idxs: np.ndarray, xs: np.ndarray,
                       weight, out: np.ndarray, counts: np.ndarray) -> None:
-        """Overwrite the given lanes with the bit-exact mapping (native
-        C++ batch evaluator; Python interpreter fallback)."""
+        """Overwrite the given lanes with the bit-exact mapping
+        (crush/wrapper.py's do_rule_batch on this rule's loaded C++
+        mapper; the interpreter where it cannot answer)."""
         if len(idxs) == 0:
             return
-        w32 = np.asarray(weight, dtype=np.uint32)
-        try:
-            nm = self._native_mapper()
-            rout, rlens = nm.do_rule_batch(
-                self.ruleno, xs[idxs].astype(np.int64),
-                self.result_max, w32)
-            out[idxs] = np.where(
-                np.arange(self.result_max)[None, :] < rlens[:, None],
-                rout.astype(np.int32), NONE)
-            counts[idxs] = rlens
-            return
-        except Exception:
-            pass
-        m = self.C.map
-        wl = [int(v) for v in w32]
-        for i in idxs:
-            res = crush_do_rule(m, self.ruleno, int(xs[i]),
-                                self.result_max, wl, self.choose_args)
-            out[i, :] = NONE
-            out[i, :len(res)] = res
-            counts[i] = len(res)
+        rout, rlens, _engine = do_rule_batch(
+            self.C.map, self.ruleno, xs[idxs], self.result_max, weight,
+            self.choose_args, mapper=self._native_mapper())
+        out[idxs] = rout.astype(np.int32)
+        counts[idxs] = rlens
 
     # ---- public -----------------------------------------------------------
     def prepare_candidates(self, xs: np.ndarray) -> None:
@@ -1114,12 +1100,11 @@ class FastRule:
         return out.copy(), counts.copy()
 
     def _native_mapper(self):
-        nm = getattr(self, "_nm", None)
-        if nm is None:
-            from ..native import NativeCrushMapper
-            nm = self._nm = NativeCrushMapper(self.C.map,
-                                              self.choose_args)
-        return nm
+        """The C++ mapper loaded with this rule's map, serialized once
+        per FastRule rather than once per epoch."""
+        if getattr(self, "_nm", None) is None:
+            self._nm = native_mapper(self.C.map, self.choose_args)
+        return self._nm
 
     @property
     def residual_fraction(self) -> float:

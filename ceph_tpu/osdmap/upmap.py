@@ -28,6 +28,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 import numpy as np
 
 from ..crush.remap import get_rule_weight_osd_map, try_remap_rule
+from ..crush.wrapper import do_rule_batch
 from .osdmap import OSDMap
 from .types import pg_t
 
@@ -44,31 +45,19 @@ class PendingInc:
 
 
 def _raw_all(m: OSDMap, pool_id: int, pool) -> List[List[int]]:
-    """RAW mapping (no upmaps) for every pg of the pool, batched via
-    the native evaluator when available (the per-iteration loop only
-    overlays upmap items on top of this, so it is computed once)."""
+    """RAW mapping (no upmaps) for every pg of the pool, in one batch
+    (the per-iteration loop only overlays upmap items on top of this,
+    so it is computed once)."""
     size = pool.size
     ruleno = m.crush.find_rule(pool.crush_rule, pool.type, size)
     if ruleno < 0:
         return [[] for _ in range(pool.pg_num)]
     pps = [pool.raw_pg_to_pps(pg_t(pool_id, ps))
            for ps in range(pool.pg_num)]
-    choose_args = m.crush.crush.choose_args.get(pool_id)
-    rows: Optional[List[List[int]]] = None
-    try:
-        from ..native import NativeCrushMapper, native_available
-        if native_available():
-            nm = NativeCrushMapper(m.crush.crush, choose_args)
-            out, lens = nm.do_rule_batch(ruleno, pps, size, m.osd_weight)
-            rows = [[int(v) for v in out[i][:lens[i]]]
-                    for i in range(len(pps))]
-    except Exception:
-        rows = None
-    if rows is None:
-        rows = [m.crush.do_rule(ruleno, x, size, m.osd_weight,
-                                choose_args_index=pool_id
-                                if choose_args is not None else None)
-                for x in pps]
+    out, lens, _engine = do_rule_batch(
+        m.crush.crush, ruleno, pps, size, m.osd_weight,
+        m.crush.crush.choose_args.get(pool_id))
+    rows = [out[i, :lens[i]].tolist() for i in range(len(pps))]
     for row in rows:
         m._remove_nonexistent_osds(pool, row)
     return rows

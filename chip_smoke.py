@@ -246,14 +246,12 @@ def phase_served(n_osds: int = 16, n_objects: int = 64,
 
 
 def _native_rows(cw, rno: int, pps: np.ndarray, weight, size: int):
-    """The native C++ mapper's rows, NONE-padded like PoolMapping.up."""
-    from ceph_tpu.crush.constants import CRUSH_ITEM_NONE
-    from ceph_tpu.native import NativeCrushMapper
-    res, lens = NativeCrushMapper(cw.crush).do_rule_batch(
-        rno, pps.tolist(), size, list(weight))
-    res, lens = np.asarray(res, np.int32), np.asarray(lens)
-    return np.where(np.arange(size)[None, :] < lens[:, None], res,
-                    CRUSH_ITEM_NONE)
+    """The C++ mapper's rows, NONE-padded like PoolMapping.up."""
+    from ceph_tpu.crush.wrapper import do_rule_batch
+    res, _lens, engine = do_rule_batch(cw.crush, rno, pps, size, weight)
+    if engine != "native":
+        raise SmokeFailure("placement: the C++ mapper did not answer")
+    return res.astype(np.int32)
 
 
 def phase_placement(n_osds: int = 1000, n_pgs: int = 100_000,

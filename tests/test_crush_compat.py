@@ -73,27 +73,6 @@ def test_weight_set_flattens_distribution_without_upmaps():
     assert len(ws) == m.pools[pid].size
 
 
-@pytest.mark.slow   # ~18 s weight-set device sweep; fast-path weight-set
-# coverage stays in tier-1 via test_batch_mapping_stays_on_device_*
-def test_device_mappers_evaluate_weight_set_bit_exactly():
-    """The optimized choose_args must map identically on the device
-    (loop kernel) and the host interpreter."""
-    from ceph_tpu.ops.crush_kernels import DeviceCrushMapper, compile_map
-    m, pid, rno = skewed_map(n_hosts=5, per_host=3, pg_num=128)
-    calc_weight_set(m, pid, max_iterations=10)
-    args = m.crush.crush.choose_args[pid]
-    cw = m.crush
-    comp = compile_map(cw.crush, args)
-    dev = DeviceCrushMapper(comp, rno, 3)
-    xs = np.arange(400, dtype=np.uint32)
-    weight = [0x10000] * m.max_osd
-    res, cnt = dev.map_batch(xs, weight)
-    for x in range(400):
-        expect = cw.do_rule(rno, int(x), 3, weight,
-                            choose_args_index=pid)
-        assert list(res[x, :cnt[x]]) == expect, x
-
-
 @pytest.mark.slow   # ~17 s weight-set device sweep heavyweight
 def test_batch_mapping_uses_weight_set():
     """OSDMapMapping's whole-map batch path must agree with the scalar
@@ -133,11 +112,14 @@ def test_mgr_crush_compat_mode_publishes():
 @pytest.mark.slow   # ~25-40 s of XLA compile+replay on 1 core: the
 # indep/exact64 heavyweights run in the slow tier so tier-1 fits its
 # wall budget (they were enable_x64-broken in the seed; fixed in PR 1)
-def test_fast_path_firstn_weight_set_bit_exact():
+@pytest.mark.parametrize("weights", ["all_in", "two_out", "reweighted"])
+def test_fast_path_firstn_weight_set_bit_exact(weights):
     """The candidate-table fast path evaluates firstn rules under
     per-position weight sets bit-exactly: positions index by the
     DYNAMIC outpos (mapper.c:513), materialized as a candidate axis
-    and gathered by each lane's success count during resolution."""
+    and gathered by each lane's success count during resolution.
+    ``all_in`` is the optimized choose_args as calc_weight_set leaves
+    them, every OSD in."""
     from ceph_tpu.ops.crush_fast import compile_fast_rule
     m, pid, rno = skewed_map(n_hosts=5, per_host=3, pg_num=128)
     calc_weight_set(m, pid, max_iterations=10)
@@ -147,15 +129,14 @@ def test_fast_path_firstn_weight_set_bit_exact():
     fr = compile_fast_rule(cw.crush, rno, 3, choose_args=args)
     assert fr.posP > 1 and fr.firstn
     xs = np.arange(400, dtype=np.uint32)
-    rng = np.random.default_rng(3)
-    for w in ([0x10000] * m.max_osd,
-              [0x10000] * (m.max_osd - 2) + [0, 0x8000],
-              list(rng.integers(0, 5, m.max_osd) * 0x4000)):
-        res, cnt = fr.map_batch(xs, np.asarray(w, np.uint32))
-        for x in range(len(xs)):
-            expect = cw.do_rule(rno, int(x), 3, list(w),
-                                choose_args_index=pid)
-            assert list(res[x, :cnt[x]]) == expect, (x, w[:4])
+    w = {"all_in": [0x10000] * m.max_osd,
+         "two_out": [0x10000] * (m.max_osd - 2) + [0, 0x8000],
+         "reweighted": list(np.random.default_rng(3).integers(
+             0, 5, m.max_osd) * 0x4000)}[weights]
+    res, cnt = fr.map_batch(xs, np.asarray(w, np.uint32))
+    for x in range(len(xs)):
+        expect = crush_do_rule(cw.crush, rno, int(x), 3, list(w), args)
+        assert list(res[x, :cnt[x]]) == expect, (x, w[:4])
 
 
 @pytest.mark.slow   # ~25-40 s of XLA compile+replay on 1 core: the

@@ -1,20 +1,24 @@
-"""The per-PG CRUSH lookup, ``CrushWrapper.do_rule``, against the Python
-interpreter ``crush.mapper.crush_do_rule``, its oracle.
+"""The choice between the C++ engine and the Python interpreter
+(``crush/wrapper.py``), against the interpreter
+``crush.mapper.crush_do_rule``, its oracle.
 
-``do_rule`` evaluates on the C++ engine (``native.NativeCrushMapper``)
-where the library loads, and on the interpreter where it does not,
-where the engine refuses the map (a malformed ``choose_args``), or
-where a choose-tries histogram is armed.  Whichever runs, the placement
-is the interpreter's, for every bucket alg, rule shape, tunables
-profile, weight vector and ``choose_args``; and each evaluation leaves
-one ``crush.scalar`` profiler span whose ``impl`` names the engine.
+``CrushWrapper.do_rule`` (per x) and ``do_rule_batch`` (the batch
+callers: ``OSDMapMapping``, upmap, ``crush_fast``'s residual replay,
+``crushtool --test``) evaluate on the C++ engine
+(``native.NativeCrushMapper``) where the library loads, and on the
+interpreter where it does not, where the engine refuses the map (a
+malformed ``choose_args``), or where a choose-tries histogram is armed.
+Whichever runs, the placement is the interpreter's, for every bucket
+alg, rule shape, tunables profile, weight vector and ``choose_args``;
+each ``do_rule`` evaluation leaves one ``crush.scalar`` profiler span
+whose ``impl`` names the engine, and ``do_rule_batch`` returns its name.
 """
 import numpy as np
 import pytest
 
 from ceph_tpu import native
 from ceph_tpu.crush import (
-    CrushWrapper, CRUSH_BUCKET_LIST, CRUSH_BUCKET_STRAW,
+    CrushWrapper, CRUSH_BUCKET_LIST, CRUSH_ITEM_NONE, CRUSH_BUCKET_STRAW,
     CRUSH_BUCKET_STRAW2, CRUSH_BUCKET_TREE, CRUSH_BUCKET_UNIFORM,
     PG_POOL_TYPE_ERASURE,
 )
@@ -22,7 +26,9 @@ from ceph_tpu.crush.constants import (
     CRUSH_RULE_CHOOSE_INDEP, CRUSH_RULE_EMIT, CRUSH_RULE_TAKE,
 )
 from ceph_tpu.crush.mapper import crush_do_rule
-from ceph_tpu.crush.types import ChooseArg, Rule, RuleStep, WeightSet
+from ceph_tpu.crush.types import (
+    ChooseArg, CrushMap, Rule, RuleStep, WeightSet,
+)
 from ceph_tpu.trace import g_tracer
 
 pytestmark = pytest.mark.skipif(
@@ -34,10 +40,12 @@ ALGS = {"uniform": CRUSH_BUCKET_UNIFORM, "list": CRUSH_BUCKET_LIST,
 PROFILES = ("argonaut", "bobtail", "firefly", "hammer", "jewel")
 
 
-def _map(rng, host_alg, n_hosts=6, per_host=4, root_alg=CRUSH_BUCKET_STRAW2):
-    """Hosts of *host_alg* under a root of *root_alg*; uneven weights
-    except in uniform buckets, which hold one weight."""
-    cw = CrushWrapper()
+def _map(rng, host_alg, n_hosts=6, per_host=4, root_alg=CRUSH_BUCKET_STRAW2,
+         cw=None):
+    """Hosts of *host_alg* under a root of *root_alg* (into *cw* where
+    given); uneven weights except in uniform buckets, which hold one
+    weight."""
+    cw = CrushWrapper() if cw is None else cw
     n = n_hosts * per_host
     cw.set_max_devices(n)
     cw.set_type_name(1, "host")
@@ -301,3 +309,194 @@ def test_native_seam_fills_armed_choose_tries_histogram(spans):
     m.choose_tries = None                   # disarmed: native again
     cw.do_rule(rno, 0, 4, weight)
     assert spans[-1] == {"impl": "native"}
+
+
+# ---- the one native-or-interpreter guard, at every call site --------------
+
+def _guarded_map():
+    """A hammer-tunables straw2 map: the device mapper refuses it (its
+    firstn chooseleaf is not stable), the C++ mapper takes it."""
+    from ceph_tpu.osdmap.osdmap import OSDMap
+    from ceph_tpu.osdmap.types import TYPE_REPLICATED, pg_pool_t
+    rng = np.random.default_rng(91)
+    osdmap = OSDMap()
+    _cw, n = _map(rng, CRUSH_BUCKET_STRAW2, cw=osdmap.crush)
+    osdmap.crush.set_tunables_profile("hammer")
+    for o in range(n):
+        osdmap.set_osd(o, up=True)
+    osdmap.osd_weight[5] = 0
+    osdmap.osd_weight[9] = 0x8000
+    rno = osdmap.crush.add_simple_rule("r", "default", "host")
+    pid = osdmap.add_pool("p", pg_pool_t(
+        type=TYPE_REPLICATED, size=3, min_size=2, crush_rule=rno,
+        pg_num=48, pgp_num=48))
+    return osdmap, pid, rno
+
+
+def _oracle(m, rno, xs, numrep, weight):
+    return [crush_do_rule(m, rno, int(x), numrep, list(weight))
+            for x in xs]
+
+
+def _site_do_rule(spans, batch_engines):
+    osdmap, _pid, rno = _guarded_map()
+    cw, w = osdmap.crush, osdmap.osd_weight
+    want = _oracle(cw.crush, rno, range(40), 3, w)
+    spans.clear()                       # the oracle's own spans
+    assert [cw.do_rule(rno, x, 3, w) for x in range(40)] == want
+    assert not batch_engines
+    return {s["impl"] for s in spans}
+
+
+def _site_raw_batch(spans, batch_engines):
+    from ceph_tpu.osdmap.mapping import OSDMapMapping, pool_pps
+    osdmap, pid, rno = _guarded_map()
+    pool = osdmap.pools[pid]
+    pps = pool_pps(pool, pid, np.arange(pool.pg_num, dtype=np.uint32))
+    mapping = OSDMapMapping(use_device=False)
+    raw = mapping._raw_batch(osdmap, pid, pool, pps)
+    got = [[int(v) for v in row if v != CRUSH_ITEM_NONE] for row in raw]
+    assert got == _oracle(osdmap.crush.crush, rno, pps, 3,
+                          osdmap.osd_weight)
+    backend = mapping.last_backend[pid]
+    assert backend == {"native": "native", "python": "host"}[
+        batch_engines[-1]]
+    return set(batch_engines)
+
+
+def _site_upmap_raw_all(spans, batch_engines):
+    from ceph_tpu.osdmap.types import pg_t
+    from ceph_tpu.osdmap.upmap import _raw_all
+    osdmap, pid, rno = _guarded_map()
+    pool = osdmap.pools[pid]
+    pps = [pool.raw_pg_to_pps(pg_t(pid, ps)) for ps in range(pool.pg_num)]
+    want = _oracle(osdmap.crush.crush, rno, pps, 3, osdmap.osd_weight)
+    spans.clear()                       # the oracle's own spans
+    assert _raw_all(osdmap, pid, pool) == want
+    # one interpreter span per PG where the interpreter answered, none
+    # from the C++ batch: no per-PG retry through do_rule
+    interp = pool.pg_num if batch_engines == ["python"] else 0
+    assert spans == [{"impl": "python"}] * interp
+    return set(batch_engines)
+
+
+def _site_replay_exact(spans, batch_engines):
+    from ceph_tpu.ops.crush_fast import compile_fast_rule
+    cw, rno, weight = _small()
+    fr = compile_fast_rule(cw.crush, rno, 4)
+    xs = np.arange(64, dtype=np.uint32)
+    out = np.full((64, 4), -5, dtype=np.int32)
+    counts = np.zeros(64, dtype=np.int32)
+    lanes = np.arange(0, 64, 3)         # the lanes forced to replay
+    fr._replay_exact(lanes, xs, np.asarray(weight, np.uint32), out, counts)
+    want = _oracle(cw.crush, rno, xs[lanes], 4, weight)
+    assert [out[i, :counts[i]].tolist() for i in lanes] == want
+    assert (out[np.setdiff1d(xs, lanes)] == -5).all()
+    return set(batch_engines)
+
+
+def _site_tester(spans, batch_engines):
+    import io
+    from ceph_tpu.crush.tester import CrushTester
+    osdmap, _pid, rno = _guarded_map()
+    w = osdmap.osd_weight
+    out, cnt = CrushTester(osdmap.crush, out=io.StringIO())._map_batch(
+        rno, list(range(40)), 3, w)
+    got = [out[i, :cnt[i]].tolist() for i in range(40)]
+    assert got == _oracle(osdmap.crush.crush, rno, range(40), 3, w)
+    return set(batch_engines)
+
+
+SITES = {"do_rule": _site_do_rule, "raw_batch": _site_raw_batch,
+         "upmap_raw_all": _site_upmap_raw_all,
+         "replay_exact": _site_replay_exact, "tester": _site_tester}
+
+
+@pytest.fixture
+def batch_engines(monkeypatch):
+    """The engine named by every ``do_rule_batch`` call a site makes."""
+    from ceph_tpu.crush import tester, wrapper
+    from ceph_tpu.ops import crush_fast
+    from ceph_tpu.osdmap import mapping, upmap
+    seen = []
+    real = wrapper.do_rule_batch
+
+    def recording(*args, **kw):
+        rows, counts, engine = real(*args, **kw)
+        seen.append(engine)
+        return rows, counts, engine
+
+    for mod in (tester, crush_fast, mapping, upmap):
+        monkeypatch.setattr(mod, "do_rule_batch", recording)
+    return seen
+
+
+def _refuse(monkeypatch, how):
+    """The binding refuses every map: ``serialize_map`` with the
+    ValueError a malformed ``choose_args`` raises, or the C++ parser
+    with its RuntimeError."""
+    def parse_failed(*_a, **_k):
+        raise RuntimeError("native map parse failed")
+
+    def malformed(*_a, **_k):
+        raise ValueError("choose_args ids len != bucket size")
+
+    if how == "serialize":
+        monkeypatch.setattr(native, "serialize_map", malformed)
+    else:
+        monkeypatch.setattr(native.NativeCrushMapper, "do_rule",
+                            parse_failed)
+        monkeypatch.setattr(native.NativeCrushMapper, "do_rule_batch",
+                            parse_failed)
+
+
+@pytest.mark.parametrize("condition", ["library", "no_library",
+                                       "choose_tries_armed",
+                                       "refused_serialize",
+                                       "refused_parse"])
+@pytest.mark.parametrize("site", sorted(SITES))
+def test_engine_guard_at_every_call_site(site, condition, spans,
+                                         batch_engines, monkeypatch):
+    """Each CRUSH call site maps exactly as the interpreter does and
+    names the engine the one guard picked: the C++ mapper where the
+    library loads, no choose-tries histogram is armed and the binding
+    takes the map; else the interpreter."""
+    if condition == "no_library":
+        monkeypatch.setattr(native, "native_available", lambda: False)
+    elif condition == "choose_tries_armed":
+        # every map armed, as crushtool --show-choose-tries arms one;
+        # room for the 100 tries of an indep rule
+        monkeypatch.setattr(CrushMap, "choose_tries", [0] * 128,
+                            raising=False)
+    elif condition.startswith("refused"):
+        _refuse(monkeypatch, condition.split("_")[1])
+    want = "native" if condition == "library" else "python"
+    assert SITES[site](spans, batch_engines) == {want}
+
+
+def test_replay_loads_the_native_mapper_once(monkeypatch):
+    """Residual lanes on every epoch replay on one C++ mapper, loaded
+    (and its map serialized) once per compiled rule."""
+    from ceph_tpu.ops.crush_fast import compile_fast_rule
+    built = []
+
+    class Counting(native.NativeCrushMapper):
+        def __init__(self, *a, **k):
+            built.append(1)
+            super().__init__(*a, **k)
+
+    monkeypatch.setattr(native, "NativeCrushMapper", Counting)
+    cw, n = _map(np.random.default_rng(95), CRUSH_BUCKET_STRAW2)
+    rno = cw.add_simple_rule("r", "default", "host")
+    fr = compile_fast_rule(cw.crush, rno, 3, tries_cap=1)
+    xs = np.arange(300, dtype=np.uint32)
+    rng = np.random.default_rng(96)
+    residual = 0.0
+    for _epoch in range(3):
+        weight = [int(v) for v in rng.choice([0, 0x4000, 0x10000], size=n)]
+        res, cnt = fr.map_batch(xs, weight)
+        residual += fr.residual_fraction
+        assert [res[x, :cnt[x]].tolist() for x in range(300)] == \
+            _oracle(cw.crush, rno, xs, 3, weight)
+    assert residual > 0
+    assert len(built) == 1

@@ -194,11 +194,14 @@ def test_batch_mapping_matches_scalar(ec):
         assert bactp == actp, ps
 
 
-def test_batch_mapping_host_fallback_agrees():
+def test_batch_mapping_host_fallback_agrees(monkeypatch):
+    from ceph_tpu import native
     m, pid, n = build_osdmap(pg_num=64)
     dev = OSDMapMapping(use_device=True)
-    host = OSDMapMapping(use_device=False, use_native=False)
+    host = OSDMapMapping(use_device=False)
     dev.update(m)
+    # no native library: the interpreter answers
+    monkeypatch.setattr(native, "native_available", lambda: False)
     host.update(m)
     for ps in range(64):
         assert dev.get(pg_t(pid, ps)) == host.get(pg_t(pid, ps))

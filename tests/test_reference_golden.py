@@ -218,13 +218,26 @@ def test_binary_fixture_mappings_match_reference(t_name, map_name, stride):
     assert total > 100, total
 
 
-def test_set_choose_mappings_on_device_legacy_path():
-    """The SAME 36864 recorded reference mappings, evaluated by the
-    DEVICE legacy fast path (ops/crush_legacy.py: straw v1 draws, local
-    tries, perm fallback, chooseleaf machine) instead of the host
-    interpreter — VERDICT r2 #3's reference-golden-on-device criterion."""
+def _set_choose_served(m, rule, xs, numrep, w):
+    """*rule* for every x on the chain that serves this straw(v1) map:
+    the device mapper refuses it, the batch seam answers."""
     import numpy as np
-    from ceph_tpu.ops.crush_legacy import LegacyFastRule
+    from ceph_tpu.crush.wrapper import do_rule_batch
+    from ceph_tpu.native import native_available
+    from ceph_tpu.ops.crush_fast import compile_fast_rule
+    with pytest.raises(ValueError):
+        compile_fast_rule(m, rule, numrep)
+    out, cnt, engine = do_rule_batch(m, rule, np.asarray(xs), numrep, w)
+    assert engine == ("native" if native_available() else "python")
+    return out, cnt
+
+
+def test_set_choose_mappings_on_served_batch_path():
+    """The SAME 36864 recorded reference mappings, evaluated by the
+    batch chain that serves this map (straw v1 draws, local tries, perm
+    fallback): ``crush.wrapper.do_rule_batch`` on the C++ mapper where
+    the library loads, instead of the per-x host interpreter."""
+    import numpy as np
 
     cw = _compile_text(os.path.join(REF_CLI, "set-choose.crushmap.txt"))
     m = cw.crush
@@ -241,18 +254,11 @@ def test_set_choose_mappings_on_device_legacy_path():
                 for i, expect in enumerate(results):
                     grouped.setdefault((ri, rule, nr_min + i),
                                        {})[x] = expect
-    rules = {}
     total = 0
-    residuals = []
     for (ri, rule, numrep), per_x in sorted(grouped.items()):
-        key = (rule, numrep)
-        if key not in rules:
-            rules[key] = LegacyFastRule(m, rule, numrep)
-        fr = rules[key]
         w = _weights_vector(runs[ri]["weights"], m.max_devices)
         xs = np.asarray(sorted(per_x), dtype=np.uint32)
-        out, cnt = fr.map_batch(xs, w)
-        residuals.append(fr.residual_fraction)
+        out, cnt = _set_choose_served(m, rule, xs, numrep, w)
         for i, x in enumerate(xs):
             got = [int(v) for v in out[i, :cnt[i]]]
             assert got == per_x[int(x)], (
@@ -260,18 +266,13 @@ def test_set_choose_mappings_on_device_legacy_path():
                 f"{got} != {per_x[int(x)]}")
             total += 1
     assert total == 36864, total
-    # the point is DEVICE evaluation: the host replay must be a rare
-    # escape hatch, not the engine
-    assert max(residuals) < 0.05, residuals
 
 
-def test_legacy_device_path_with_dead_slots():
+def test_served_batch_path_with_dead_slots():
     """Heavy-out weight vectors kill whole slots, driving the
-    chooseleaf recursion's outpos behind the attempt index — the device
-    machine must track the reference exactly."""
+    chooseleaf recursion's outpos behind the attempt index — the served
+    batch chain must track the reference exactly."""
     import numpy as np
-    from ceph_tpu.crush.mapper import crush_do_rule
-    from ceph_tpu.ops.crush_legacy import LegacyFastRule
 
     cw = _compile_text(os.path.join(REF_CLI, "set-choose.crushmap.txt"))
     m = cw.crush
@@ -279,12 +280,11 @@ def test_legacy_device_path_with_dead_slots():
     rng = np.random.default_rng(13)
     bad = 0
     for rule in (2, 5):              # the chooseleaf rules
-        fr = LegacyFastRule(m, rule, 3)
         for trial in range(4):
             w = [0x10000] * m.max_devices
             for d in rng.choice(m.max_devices, size=7, replace=False):
                 w[int(d)] = 0 if trial % 2 else 0x2000
-            out, cnt = fr.map_batch(xs, w)
+            out, cnt = _set_choose_served(m, rule, xs, 3, w)
             for x in range(len(xs)):
                 exp = crush_do_rule(m, rule, int(x), 3, w)
                 if [int(v) for v in out[x, :cnt[x]]] != exp:
