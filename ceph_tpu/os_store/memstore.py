@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 from ..common.lockdep import DebugRLock
+from ..trace.span import g_tracer
 from .device_shard import DeviceShard, g_device_budget
 
 
@@ -115,6 +116,76 @@ class Transaction:
 
     def empty(self) -> bool:
         return not self.ops
+
+
+class _Stage:
+    """One transaction's copy-on-write view of the store.
+
+    Each collection the transaction names is a shallow copy: a new dict
+    of the committed objects.  An object gets a private ``_Object`` on
+    its first touch (``obj``), with its own attr and omap dicts but the
+    committed body still shared; the body is copied only when an op
+    edits it in place (``body``), and then only the bytes the edit
+    keeps.  So nothing the committed store holds is ever mutated, and an
+    op that raises leaves it exactly as it was.  ``staged_bytes`` and
+    ``staged_objs`` count the body bytes copied and the objects copied
+    to stage."""
+
+    __slots__ = ("base", "colls", "staged_bytes", "staged_objs")
+
+    def __init__(self, base: Dict[str, Dict[hobject_t, _Object]],
+                 cids) -> None:
+        self.base = base
+        self.colls = dict(base)
+        for cid in cids:
+            coll = base.get(cid)
+            if coll is not None:
+                self.colls[cid] = dict(coll)
+        self.staged_bytes = 0
+        self.staged_objs = 0
+
+    def _committed(self, cid: str, oid: hobject_t) -> Optional[_Object]:
+        coll = self.base.get(cid)
+        return None if coll is None else coll.get(oid)
+
+    def coll(self, cid: str) -> Dict[hobject_t, _Object]:
+        if cid not in self.colls:
+            raise KeyError(f"no collection {cid}")
+        return self.colls[cid]
+
+    def obj(self, cid: str, oid: hobject_t, create: bool = False) -> _Object:
+        """The staged object, private to this transaction."""
+        c = self.coll(cid)
+        o = c.get(oid)
+        if o is None:
+            if not create:
+                raise KeyError(f"no object {oid} in {cid}")
+            o = c[oid] = _Object()
+        elif o is self._committed(cid, oid):
+            mine = c[oid] = _Object()
+            mine.data = o.data
+            mine.attrs = dict(o.attrs)
+            mine.omap = dict(o.omap)
+            self.staged_objs += 1
+            o = mine
+        return o
+
+    def body(self, cid: str, oid: hobject_t, o: _Object,
+             keep: Optional[int] = None) -> bytearray:
+        """*o*'s body as a bytearray this transaction may edit in place.
+        A resident shard materializes (byte-granular edits need bytes);
+        a body still shared with the committed object is copied, its
+        first *keep* bytes only where the edit rewrites or drops the
+        rest (None: all of it)."""
+        d = o.data
+        if isinstance(d, DeviceShard):
+            o.data = d = bytearray(d.materialize())
+        else:
+            base = self._committed(cid, oid)
+            if base is not None and d is base.data:
+                o.data = d = bytearray(memoryview(d)[:keep])
+                self.staged_bytes += len(d)
+        return d
 
 
 _MAGIC = b"CTPUSTOR"
@@ -219,75 +290,44 @@ class MemStore:
 
     # ---- transactions -----------------------------------------------------
     def queue_transaction(self, t: Transaction) -> None:
-        """Apply atomically; invalid ops raise before any mutation.
+        """Apply atomically; an op that raises leaves the store as it was.
 
         Thread-safe for writers (the threaded op queue commits from
         worker threads; the reference ObjectStore is too): the whole
         stage-and-swap runs under a mutex, while readers see either the
-        old or the new dict via the atomic reference swap."""
+        old or the new dict via the atomic reference swap.  Staging is
+        copy-on-write per object (``_Stage``): no committed object is
+        ever edited, so a reader holding the old dict or an old object
+        keeps seeing the old state."""
         with self._write_lock:
-            # stage (deep-clone) only the collections this transaction
-            # touches; untouched ones share by reference — the swap
-            # below is still one atomic rebind for readers, and the
-            # critical section stops scaling with the WHOLE store
-            touched = {op[1] for op in t.ops if len(op) > 1}
-            staged = dict(self.colls)
-            for cid in touched:
-                coll = self.colls.get(cid)
-                if coll is not None:
-                    staged[cid] = {o: self._clone(obj)
-                                   for o, obj in coll.items()}
-            self._apply(staged, t)
-            self.colls = staged
-            self.committed_txns += 1
+            scope = g_tracer.span(prof="os.queue_transaction",
+                                  staged_bytes=0, staged_objs=0)
+            with scope:
+                stage = _Stage(self.colls,
+                               {op[1] for op in t.ops if len(op) > 1})
+                self._apply(stage, t)
+                self.colls = stage.colls
+                self.committed_txns += 1
+                scope.set(staged_bytes=stage.staged_bytes,
+                          staged_objs=stage.staged_objs)
 
     @staticmethod
-    def _clone(obj: _Object) -> _Object:
-        c = _Object()
-        # a DeviceShard is immutable-by-convention (mutations replace
-        # the whole body or materialize first) — clones share the
-        # handle so staging a touched collection moves no device bytes
-        c.data = obj.data if isinstance(obj.data, DeviceShard) \
-            else bytearray(obj.data)
-        c.attrs = dict(obj.attrs)
-        c.omap = dict(obj.omap)
-        return c
-
-    @staticmethod
-    def _mutable(o: _Object) -> bytearray:
-        """The object's body as a spliceable bytearray; a resident
-        shard materializes first (byte-granular edits need bytes)."""
-        if isinstance(o.data, DeviceShard):
-            o.data = bytearray(o.data.materialize())
-        return o.data
-
-    def _apply(self, colls, t: Transaction) -> None:
-        def coll(cid):
-            if cid not in colls:
-                raise KeyError(f"no collection {cid}")
-            return colls[cid]
-
-        def obj(cid, oid, create=False):
-            c = coll(cid)
-            if oid not in c:
-                if not create:
-                    raise KeyError(f"no object {oid} in {cid}")
-                c[oid] = _Object()
-            return c[oid]
-
+    def _apply(stage: _Stage, t: Transaction) -> None:
+        obj, body = stage.obj, stage.body
         for op in t.ops:
             code = op[0]
             if code == OP_MKCOLL:
-                colls.setdefault(op[1], {})
+                stage.colls.setdefault(op[1], {})
             elif code == OP_RMCOLL:
-                colls.pop(op[1], None)
+                stage.colls.pop(op[1], None)
             elif code == OP_TOUCH:
                 obj(op[1], op[2], create=True)
             elif code == OP_WRITE:
                 _, cid, oid, offset, data = op
                 o = obj(cid, oid, create=True)
-                buf = self._mutable(o)
                 end = offset + len(data)
+                buf = body(cid, oid, o,
+                           offset if end >= len(o.data) else None)
                 if len(buf) < end:
                     buf.extend(b"\0" * (end - len(buf)))
                 buf[offset:end] = data
@@ -297,21 +337,21 @@ class MemStore:
             elif code == OP_ZERO:
                 _, cid, oid, offset, length = op
                 o = obj(cid, oid, create=True)
-                buf = self._mutable(o)
                 end = offset + length
+                buf = body(cid, oid, o,
+                           offset if end >= len(o.data) else None)
                 if len(buf) < end:
                     buf.extend(b"\0" * (end - len(buf)))
                 buf[offset:end] = b"\0" * length
             elif code == OP_TRUNCATE:
                 _, cid, oid, size = op
-                o = obj(cid, oid, create=True)
-                buf = self._mutable(o)
+                buf = body(cid, oid, obj(cid, oid, create=True), size)
                 if len(buf) > size:
                     del buf[size:]
                 else:
                     buf.extend(b"\0" * (size - len(buf)))
             elif code == OP_REMOVE:
-                coll(op[1]).pop(op[2], None)
+                stage.coll(op[1]).pop(op[2], None)
             elif code == OP_SETATTR:
                 _, cid, oid, name, value = op
                 obj(cid, oid, create=True).attrs[name] = value

@@ -67,17 +67,22 @@ def cluster_and_text():
         and any(ho.oid == "om" for ho in o.store.list_objects(cid)))
     c.kill_osd(victim)
     c.mark_osd_down(victim)
+    from ceph_tpu.mesh import mesh_decode_perf_counters
+    from ceph_tpu.mesh.runtime import l_mdec_dispatches, l_mdec_fallbacks
+    # the counters are process-wide: an earlier test in this process
+    # (a device fault in test_mesh_decode.py) may have moved them
+    fallbacks0 = mesh_decode_perf_counters().get(l_mdec_fallbacks)
     g_conf.set_val("ec_mesh_chips", 8)
     try:
         assert cl.read("lint", "om")[:1] == b"m"
     finally:
         g_conf.rm_val("ec_mesh_chips")
         g_mesh.topology()
-    from ceph_tpu.mesh import mesh_decode_perf_counters
-    from ceph_tpu.mesh.runtime import l_mdec_dispatches
     assert mesh_decode_perf_counters().get(l_mdec_dispatches) > 0, \
         "degraded read never rode the meshed decode path — its " \
         "counter family would be lint-invisible"
+    assert mesh_decode_perf_counters().get(l_mdec_fallbacks) == \
+        fallbacks0, "the degraded read fell back off the mesh"
     c.revive_osd(victim)
     for _ in range(3):
         c.tick(dt=6.0)
@@ -217,7 +222,7 @@ def test_known_new_families_covered_by_the_lint(cluster_and_text):
     # cover the straggler-proof read path's surfaces
     assert "mesh_decode" in c.perf_collection.dump()
     assert c.perf_collection.dump()["mesh_decode"]["dispatches"] > 0
-    assert c.perf_collection.dump()["mesh_decode"]["fallbacks"] == 0
+    assert "fallbacks" in c.perf_collection.dump()["mesh_decode"]
     from ceph_tpu.trace import g_perf_histograms
     from ceph_tpu.trace.oplat import stage_of_hist_name
     assert any(lg == "devprof" for (lg, _n), _h
