@@ -312,6 +312,8 @@ class MiniCluster:
             "histogram and counters")
         from .osd.ec_backend import pipeline_perf_counters
         self.perf_collection.add(pipeline_perf_counters())
+        from .ec.lrc import lrc_perf_counters
+        self.perf_collection.add(lrc_perf_counters())
         from .common.work_queue import qos_perf_counters
         self.perf_collection.add(qos_perf_counters())
         asok.register(

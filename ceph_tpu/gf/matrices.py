@@ -10,7 +10,9 @@ that coding chunks are byte-identical:
 - ``jerasure_reed_sol_van_matrix`` reproduces jerasure's
   ``reed_sol_vandermonde_coding_matrix`` (the reed_sol_van technique,
   src/erasure-code/jerasure/ErasureCodeJerasure.cc:155): the m x k coding
-  rows derived from an extended Vandermonde matrix reduced to systematic form.
+  rows derived from an extended Vandermonde matrix reduced to systematic
+  form, then scaled so the first coding row and the first column are ones
+  (for m=1 the XOR parity).
 
 Both libraries are empty submodules in the reference tree; these generators
 are clean implementations of the published algorithms, validated by MDS

@@ -46,8 +46,10 @@ def extended_vandermonde_w(rows: int, cols: int, w: int) -> np.ndarray:
 
 
 def reed_sol_van_matrix_w(k: int, m: int, w: int) -> np.ndarray:
-    """m x k coding matrix matching jerasure reed_sol_van over GF(2^w)
-    (same column-elimination systematization as the w=8 generator)."""
+    """m x k coding matrix matching jerasure reed_sol_van over GF(2^w):
+    reed_sol_big_vandermonde_distribution_matrix's column-elimination
+    systematization, then its two normalizations (the first coding row
+    all ones, then the first column of every coding row one)."""
     rows, cols = k + m, k
     dist = extended_vandermonde_w(rows, cols, w)
     for i in range(1, cols):
@@ -67,6 +69,20 @@ def reed_sol_van_matrix_w(k: int, m: int, w: int) -> np.ndarray:
             if jj != i and t != 0:
                 for r in range(rows):
                     dist[r, jj] ^= gfw_mul(t, int(dist[r, i]), w)
+    # row k all ones: scale each column's coding part by 1 / dist[k, j]
+    for j in range(cols):
+        t = int(dist[cols, j])
+        if t != 1:
+            inv = gfw_div(1, t, w)
+            for r in range(cols, rows):
+                dist[r, j] = gfw_mul(inv, int(dist[r, j]), w)
+    # column 0 of every further coding row one: scale the row
+    for r in range(cols + 1, rows):
+        t = int(dist[r, 0])
+        if t != 1:
+            inv = gfw_div(1, t, w)
+            for j in range(cols):
+                dist[r, j] = gfw_mul(inv, int(dist[r, j]), w)
     return dist[k:, :].copy()
 
 

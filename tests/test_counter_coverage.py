@@ -223,6 +223,12 @@ def test_known_new_families_covered_by_the_lint(cluster_and_text):
     assert "mesh_decode" in c.perf_collection.dump()
     assert c.perf_collection.dump()["mesh_decode"]["dispatches"] > 0
     assert "fallbacks" in c.perf_collection.dump()["mesh_decode"]
+    # LRC canary: the layered code's repair counters are registered on
+    # every cluster, so ceph_daemon_lrc_* rides the generic lints above
+    assert {"local_repairs", "global_repairs"} <= \
+        set(c.perf_collection.dump()["lrc"])
+    assert "ceph_daemon_lrc_local_repairs" in _text
+    assert "ceph_daemon_lrc_global_repairs" in _text
     from ceph_tpu.trace import g_perf_histograms
     from ceph_tpu.trace.oplat import stage_of_hist_name
     assert any(lg == "devprof" for (lg, _n), _h

@@ -67,6 +67,19 @@ def test_gf_bit_matmul_compiles_for_v5e(one_chip, rows):
         + mem.output_size_in_bytes < 16 * 10 ** 9
 
 
+@pytest.mark.parametrize("k,m", [(4, 2), (3, 1)],
+                         ids=["lrc_global", "lrc_local"])
+def test_lrc_layer_matmuls_compile_for_v5e(one_chip, k, m):
+    """The layers of an LRC k=4 m=2 l=3 write of 4 MiB (256 stripes of
+    4 KiB chunks): the global layer, 4 chunks to 2, and each local
+    layer, 3 chunks to 1."""
+    from ceph_tpu.ops.gf_matmul import gf_bit_matmul
+    compiled = gf_bit_matmul.lower(
+        _spec((256, k, 4096), jnp.uint8, one_chip),
+        _spec((k * 8, m * 8), jnp.int8, one_chip)).compile()
+    assert compiled.out_info.shape == (256, m, 4096)
+
+
 def test_pallas_gf_kernel_compiles_for_v5e(one_chip):
     """The fused Pallas kernel, not interpreted: a real Mosaic kernel."""
     from ceph_tpu.ops.gf_pallas import _run
