@@ -16,6 +16,15 @@ HBM until a host read materializes it (the accounted
 ``memstore.fetch_shard`` d2h).  ``stat``/``save`` work unchanged via
 ``len()``/``bytes()``; any byte-granular mutation (write/zero/truncate)
 materializes first, so splicing semantics are identical either way.
+
+Adopted bodies: every queued write holds an immutable ``bytes`` (the
+caller's own ``bytes``, or the one copy ``Transaction.write`` makes of
+anything else), and a write that replaces a whole body makes that
+``bytes`` the body, as upstream's MemStore splices a message's
+bufferlist into the object: no zero-fill and no second copy.  A body is
+then a ``bytearray`` (private to the store), a ``bytes`` or a
+``DeviceShard``; every in-place edit copies the latter two first
+(``_Stage.body``).
 """
 from __future__ import annotations
 
@@ -74,6 +83,10 @@ class Transaction:
         self.ops.append((OP_TOUCH, cid, oid))
 
     def write(self, cid: str, oid: hobject_t, offset: int, data):
+        """Queue *data* at *offset* as an immutable ``bytes``: a
+        ``bytes`` is queued itself, anything else is copied once (so a
+        caller that reuses its buffer cannot reach the store), and
+        ``_apply`` may make the queued ``bytes`` a body."""
         self.ops.append((OP_WRITE, cid, oid, offset, bytes(data)))
 
     def write_shard(self, cid: str, oid: hobject_t, shard):
@@ -129,9 +142,11 @@ class _Stage:
     keeps.  So nothing the committed store holds is ever mutated, and an
     op that raises leaves it exactly as it was.  ``staged_bytes`` and
     ``staged_objs`` count the body bytes copied and the objects copied
-    to stage."""
+    to stage; ``adopted_bytes`` the body bytes that became a body by
+    reference."""
 
-    __slots__ = ("base", "colls", "staged_bytes", "staged_objs")
+    __slots__ = ("base", "colls", "staged_bytes", "staged_objs",
+                 "adopted_bytes")
 
     def __init__(self, base: Dict[str, Dict[hobject_t, _Object]],
                  cids) -> None:
@@ -143,6 +158,7 @@ class _Stage:
                 self.colls[cid] = dict(coll)
         self.staged_bytes = 0
         self.staged_objs = 0
+        self.adopted_bytes = 0
 
     def _committed(self, cid: str, oid: hobject_t) -> Optional[_Object]:
         coll = self.base.get(cid)
@@ -174,15 +190,16 @@ class _Stage:
              keep: Optional[int] = None) -> bytearray:
         """*o*'s body as a bytearray this transaction may edit in place.
         A resident shard materializes (byte-granular edits need bytes);
-        a body still shared with the committed object is copied, its
-        first *keep* bytes only where the edit rewrites or drops the
-        rest (None: all of it)."""
+        an adopted ``bytes`` body, or one still shared with the
+        committed object, is copied, its first *keep* bytes only where
+        the edit rewrites or drops the rest (None: all of it)."""
         d = o.data
         if isinstance(d, DeviceShard):
             o.data = d = bytearray(d.materialize())
         else:
             base = self._committed(cid, oid)
-            if base is not None and d is base.data:
+            if not isinstance(d, bytearray) or \
+                    (base is not None and d is base.data):
                 o.data = d = bytearray(memoryview(d)[:keep])
                 self.staged_bytes += len(d)
         return d
@@ -301,7 +318,8 @@ class MemStore:
         keeps seeing the old state."""
         with self._write_lock:
             scope = g_tracer.span(prof="os.queue_transaction",
-                                  staged_bytes=0, staged_objs=0)
+                                  staged_bytes=0, staged_objs=0,
+                                  adopted_bytes=0)
             with scope:
                 stage = _Stage(self.colls,
                                {op[1] for op in t.ops if len(op) > 1})
@@ -309,7 +327,8 @@ class MemStore:
                 self.colls = stage.colls
                 self.committed_txns += 1
                 scope.set(staged_bytes=stage.staged_bytes,
-                          staged_objs=stage.staged_objs)
+                          staged_objs=stage.staged_objs,
+                          adopted_bytes=stage.adopted_bytes)
 
     @staticmethod
     def _apply(stage: _Stage, t: Transaction) -> None:
@@ -326,11 +345,17 @@ class MemStore:
                 _, cid, oid, offset, data = op
                 o = obj(cid, oid, create=True)
                 end = offset + len(data)
-                buf = body(cid, oid, o,
-                           offset if end >= len(o.data) else None)
-                if len(buf) < end:
-                    buf.extend(b"\0" * (end - len(buf)))
-                buf[offset:end] = data
+                if offset == 0 and end >= len(o.data):
+                    # the write replaces the whole body: the queued
+                    # bytes become it, with no zero-fill and no copy
+                    o.data = data
+                    stage.adopted_bytes += end
+                else:
+                    buf = body(cid, oid, o,
+                               offset if end >= len(o.data) else None)
+                    if len(buf) < end:
+                        buf.extend(b"\0" * (end - len(buf)))
+                    buf[offset:end] = data
             elif code == OP_WRITE_SHARD:
                 _, cid, oid, shard = op
                 obj(cid, oid, create=True).data = shard
@@ -383,7 +408,9 @@ class MemStore:
                        o: _Object) -> None:
         """Fault site ``store.shard_corrupt``: flip one stored body
         byte (bitrot) — works on resident handles and host bytes alike
-        so the crc EIO path is testable in both representations."""
+        so the crc EIO path is testable in both representations.  An
+        adopted ``bytes`` body is replaced by a flipped copy, so later
+        reads still see the rot."""
         from ..fault import g_faults  # lazy: fault imports trace
         if not g_faults.site_armed("store.shard_corrupt"):
             return
@@ -394,6 +421,8 @@ class MemStore:
         if isinstance(d, DeviceShard):
             d.corrupted()
         elif len(d):
+            if not isinstance(d, bytearray):
+                o.data = d = bytearray(d)
             d[0] ^= 0x01
 
     def read(self, cid: str, oid: hobject_t, offset: int = 0,
@@ -410,7 +439,8 @@ class MemStore:
     def read_shard(self, cid: str, oid: hobject_t):
         """The whole body WITHOUT forcing host bytes: a resident
         ``DeviceShard`` comes back as the handle itself (LRU-touched);
-        host-bytes bodies come back as bytes.  The zero-copy read path
+        host-bytes bodies come back as bytes (an adopted ``bytes`` body
+        itself, a ``bytearray`` copied out).  The zero-copy read path
         for in-process fabrics."""
         o = self.colls[cid][oid]
         self._maybe_corrupt(cid, oid, o)

@@ -116,6 +116,7 @@ def test_corrupt_shard_detected_and_reconstructed():
             for ho in osd.store.list_objects(cid):
                 if ho.oid == "objc":
                     obj = osd.store.colls[cid][ho]
+                    obj.data = bytearray(obj.data)
                     obj.data[10] ^= 0xFF
                     break
             else:

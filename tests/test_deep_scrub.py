@@ -17,6 +17,14 @@ def payload(n=20000, seed=0):
         0, 256, size=n, dtype=np.uint8).tobytes()
 
 
+def _body(osd, cid, ho) -> bytearray:
+    """The stored body as a bytearray the test may rot in place (a
+    committed body may be an immutable buffer the store adopted)."""
+    o = osd.store.colls[cid][ho]
+    o.data = bytearray(o.data)
+    return o.data
+
+
 def _find_copy(c, oid, skip_primary_of=None):
     """(osd, cid, ho) of one stored copy, preferring a non-primary."""
     hits = []
@@ -52,7 +60,7 @@ def test_shallow_scrub_reads_no_data_and_misses_bitrot():
     _pg, primary = cl2._calc_target(pid, "obj")
     osd, cid, ho, = _find_copy(c, "obj", skip_primary_of=primary)
     before = bytes(osd.store.colls[cid][ho].data)
-    osd.store.colls[cid][ho].data[5] ^= 0xA5
+    _body(osd, cid, ho)[5] ^= 0xA5
     corrupted = bytes(osd.store.colls[cid][ho].data)
 
     reads = []
@@ -86,7 +94,7 @@ def test_shallow_scrub_catches_size_mismatch():
     cl2 = c.client("client.probe")
     _pg, primary = cl2._calc_target(cl2.lookup_pool("p"), "obj")
     osd, cid, ho = _find_copy(c, "obj", skip_primary_of=primary)
-    del osd.store.colls[cid][ho].data[-100:]        # silent truncation
+    del _body(osd, cid, ho)[-100:]        # silent truncation
     c.scrub(deep=False)
     assert bytes(osd.store.colls[cid][ho].data) == data
     assert cl.read("p", "obj") == data
@@ -123,7 +131,7 @@ def test_shallow_scrub_catches_ec_size_vs_hinfo():
                 continue
             for ho in osd.store.list_objects(cid):
                 if ho.oid == "obj" and ho.shard >= 0:
-                    del osd.store.colls[cid][ho].data[-16:]
+                    del _body(osd, cid, ho)[-16:]
                     c.scrub(deep=False)
                     assert cl.read("p", "obj") == data
                     return
@@ -149,7 +157,7 @@ def test_corrupt_primary_loses_majority_vote():
             continue
         for ho in posd.store.list_objects(cid):
             if ho.oid == "obj" and hit is None:
-                posd.store.colls[cid][ho].data[7] ^= 0x3C
+                _body(posd, cid, ho)[7] ^= 0x3C
                 hit = (cid, ho)
     assert hit is not None
     c.scrub(deep=True)
@@ -190,7 +198,7 @@ def test_identical_rot_on_majority_of_copies_still_repaired():
                 continue
             for ho in osd.store.list_objects(cid):
                 if ho.oid == "obj":
-                    osd.store.colls[cid][ho].data[3] ^= 0xFF
+                    _body(osd, cid, ho)[3] ^= 0xFF
                     n += 1
     assert n == 2
     c.scrub(deep=True)
@@ -265,7 +273,7 @@ def test_digestless_object_keeps_primary_authority():
                 continue
             for ho in osd.store.list_objects(cid):
                 if ho.oid == "obj":
-                    osd.store.colls[cid][ho].data[3] ^= 0xFF
+                    _body(osd, cid, ho)[3] ^= 0xFF
                     n += 1
     assert n == 2
     c.scrub(deep=True)
@@ -303,7 +311,7 @@ def test_repaired_copy_does_not_rescrub_forever():
                 continue
             for ho in osd.store.list_objects(cid):
                 if ho.oid == "obj" and hit == 0:
-                    osd.store.colls[cid][ho].data[3] ^= 0x55
+                    _body(osd, cid, ho)[3] ^= 0x55
                     hit += 1
     assert hit == 1
     c.scrub(deep=True)          # finds + repairs (push mints a digest)

@@ -88,7 +88,9 @@ def test_scrub_inconsistency_reaches_cluster_log():
                 continue
             for ho in osd.store.list_objects(cid):
                 if ho.oid == "obj" and hit == 0:
-                    osd.store.colls[cid][ho].data[3] ^= 0xFF
+                    obj = osd.store.colls[cid][ho]
+                    obj.data = bytearray(obj.data)
+                    obj.data[3] ^= 0xFF
                     hit += 1
     assert hit == 1
     c.scrub(deep=True)

@@ -6,7 +6,10 @@ committed body, and copies a body only when an op edits it in place.
 These tests hold that to the guarantees the store gave when it
 deep-cloned whole collections: a failed transaction changes nothing, a
 reader's snapshot never changes under it, and the journal replays to
-the live state.  Each runs on a ``MemStore`` and on an unmounted and a
+the live state.  A whole-body write is adopted: the ``bytes`` the
+transaction queued (the caller's own ``bytes``, else its one copy)
+becomes the body by reference, and any later in-place edit copies it
+first.  Each runs on a ``MemStore`` and on an unmounted and a
 mounted ``WALStore`` (which calls ``MemStore.queue_transaction`` inside
 its journal write).
 """
@@ -202,6 +205,7 @@ def test_resident_body_is_shared_by_handle(spans):
 
 def test_shard_corrupt_flips_the_live_body(store):
     from ceph_tpu.fault import g_faults
+    assert isinstance(store.colls[CID][A].data, bytes)   # adopted
     body = store.read(CID, A)
     g_faults.inject("store.shard_corrupt", mode="once")
     try:
@@ -255,3 +259,173 @@ def test_wal_replay_equals_live(tmp_path, wal_max_bytes):
     assert replayed.committed_txns == live.committed_txns
     replayed.umount()
     live._wal_f.close()
+
+
+def _frozen_view(data: bytes) -> memoryview:
+    """A read-only memoryview over a read-only ndarray."""
+    a = np.frombuffer(bytearray(data), dtype=np.uint8)
+    a.flags.writeable = False
+    return a.data
+
+
+@pytest.mark.parametrize("kind", ["bytes", "readonly_view"])
+def test_read_only_write_is_adopted(store, spans, kind):
+    data = payload(8192, 7)
+    buf = data if kind == "bytes" else _frozen_view(data)
+    t = Transaction()
+    t.truncate(CID, A, 0)
+    t.write(CID, A, 0, buf)
+    t.write(CID, hobject_t("c", 0), 0, buf)      # a new object
+    store.queue_transaction(t)
+    a, c = store.colls[CID][A].data, store.colls[CID][hobject_t("c", 0)].data
+    assert type(a) is bytes and type(c) is bytes
+    if kind == "bytes":
+        assert a is buf and c is buf
+    else:                       # one copy each, made at queue time
+        assert a is not c
+    assert spans[-1]["adopted_bytes"] == 2 * len(data)
+    assert spans[-1]["staged_bytes"] == 0
+    assert store.read(CID, A) == data
+    assert store.stat(CID, A) == len(data)
+    got = store.read_shard(CID, A)
+    assert got == data and isinstance(got, bytes)
+    if kind == "bytes":
+        assert got is buf
+    # a write at offset 0 at least as long as the body adopts too
+    longer = payload(9000, 8)
+    t = Transaction()
+    t.write(CID, A, 0, longer)
+    store.queue_transaction(t)
+    assert store.colls[CID][A].data is longer
+    assert spans[-1]["adopted_bytes"] == len(longer)
+
+
+@pytest.mark.parametrize("kind", ["bytearray", "ndarray", "writable_view"])
+def test_writable_buffer_is_copied(store, kind):
+    data = payload(4096, 9)
+    if kind == "bytearray":
+        buf = bytearray(data)
+    elif kind == "ndarray":
+        buf = np.frombuffer(bytearray(data), dtype=np.uint8)
+    else:
+        buf = memoryview(bytearray(data))
+    t = Transaction()
+    t.truncate(CID, A, 0)
+    t.write(CID, A, 0, buf)
+    store.queue_transaction(t)
+    buf[0] ^= 0xFF
+    buf[-1] ^= 0xFF
+    assert store.colls[CID][A].data is not buf
+    assert store.read(CID, A) == data
+
+
+@pytest.mark.parametrize("kind", ["memoryview", "ndarray"])
+def test_read_only_view_over_a_writable_base_is_copied(store, kind):
+    """A read-only view says nothing of its owner: the owner may still
+    write the bytes, so the store keeps a copy, never the view."""
+    data = payload(4096, 16)
+    base = bytearray(data)
+    if kind == "memoryview":
+        buf = memoryview(base).toreadonly()
+    else:
+        buf = np.frombuffer(base, dtype=np.uint8)[:]
+        buf.flags.writeable = False
+    assert memoryview(buf).readonly
+    t = Transaction()
+    t.write(CID, A, 0, buf)
+    store.queue_transaction(t)
+    base[0] ^= 0xFF
+    base[-1] ^= 0xFF
+    assert type(store.colls[CID][A].data) is bytes
+    assert store.read(CID, A) == data
+
+
+@pytest.mark.parametrize("edit,kept", [
+    (lambda t: t.write(CID, A, 100, b"X" * 64), 4096),
+    (lambda t: t.write(CID, A, 4000, b"Y" * 200), 4000),
+    (lambda t: t.zero(CID, A, 8, 16), 4096),
+    (lambda t: t.truncate(CID, A, 1000), 1000),
+    (lambda t: t.truncate(CID, A, 8192), 4096),
+], ids=["write", "write_past_end", "zero", "shrink", "grow"])
+@pytest.mark.parametrize("kind", ["bytes", "readonly_view"])
+def test_edit_of_adopted_body_copies_on_write(store, spans, kind, edit,
+                                              kept):
+    data = payload(4096, 10)
+    buf = data if kind == "bytes" else _frozen_view(data)
+    t = Transaction()
+    t.write(CID, A, 0, buf)
+    store.queue_transaction(t)
+    old_colls, old = store.colls, store.colls[CID][A]
+    body = old.data
+    assert type(body) is bytes and body == data
+    assert (body is buf) == (kind == "bytes")
+    t = Transaction()
+    edit(t)
+    store.queue_transaction(t)
+    assert spans[-1]["staged_bytes"] == kept
+    assert spans[-1]["adopted_bytes"] == 0
+    assert bytes(buf) == data                    # the buffer is untouched
+    assert old_colls[CID][A] is old and old.data is body
+    assert body == data
+    assert store.read(CID, A) != data
+    # an edit inside the same transaction as the adopting write
+    t = Transaction()
+    t.write(CID, B, 0, buf)
+    t.write(CID, B, 4, b"abcd")
+    store.queue_transaction(t)
+    assert store.read(CID, B) == data[:4] + b"abcd" + data[8:]
+    assert bytes(buf) == data
+    assert spans[-1]["adopted_bytes"] == len(data)
+    assert spans[-1]["staged_bytes"] == len(data)
+
+
+def test_failed_transaction_after_adopting_write(store, spans):
+    before, colls = state(store), store.colls
+    t = Transaction()
+    t.truncate(CID, A, 0)
+    t.write(CID, A, 0, payload(8192, 11))
+    t.write(CID, hobject_t("new", 0), 0, _frozen_view(payload(64, 12)))
+    t.rmattr(CID, hobject_t("missing", 0), "v")  # raises
+    with pytest.raises(KeyError):
+        store.queue_transaction(t)
+    assert store.colls is colls
+    assert state(store) == before
+
+
+def test_shard_corrupt_flips_an_adopted_view(store):
+    from ceph_tpu.fault import g_faults
+    data = payload(4096, 13)
+    buf = _frozen_view(data)
+    t = Transaction()
+    t.write(CID, A, 0, buf)
+    store.queue_transaction(t)
+    g_faults.inject("store.shard_corrupt", mode="once")
+    try:
+        got = store.read(CID, A)
+    finally:
+        g_faults.clear()
+    assert got[0] == data[0] ^ 0x01 and got[1:] == data[1:]
+    assert store.read(CID, A) == got             # the rot stays
+    assert bytes(buf) == data                    # the sender's buffer not
+
+
+def test_save_load_and_wal_replay_keep_an_adopted_body(tmp_path):
+    data, view = payload(6000, 14), _frozen_view(payload(5000, 15))
+    d = str(tmp_path / "osd")
+    live = WALStore(d, wal_max_bytes=1 << 30)
+    live.mount()
+    t = Transaction()
+    t.create_collection(CID)
+    t.write(CID, A, 0, data)
+    t.write(CID, B, 0, view)
+    live.queue_transaction(t)
+    assert live.colls[CID][A].data is data
+    assert type(live.colls[CID][B].data) is bytes
+    want = state(live)
+    replayed = WALStore(d)
+    replayed.mount()                             # no umount: a crash
+    assert state(replayed) == want
+    replayed.umount()
+    live.save(str(tmp_path / "snap"))
+    assert state(MemStore.load(str(tmp_path / "snap"))) == want
+    live.umount()

@@ -27,6 +27,7 @@ def _corrupt_one_shard(c, oid):
                 if ho.oid == oid and ho.shard >= 0:
                     obj = osd.store.colls[cid][ho]
                     before = bytes(obj.data)
+                    obj.data = bytearray(obj.data)
                     obj.data[7] ^= 0x5A
                     return osd.osd_id, cid, ho, before
     raise AssertionError("no shard found")
@@ -100,7 +101,9 @@ def test_scrub_replicated_digest_mismatch():
                 continue
             for ho in osd.store.list_objects(cid):
                 if ho.oid == "ro":
-                    osd.store.colls[cid][ho].data[3] ^= 0xFF
+                    obj = osd.store.colls[cid][ho]
+                    obj.data = bytearray(obj.data)
+                    obj.data[3] ^= 0xFF
                     victim = osd.osd_id
                     break
     c.scrub()
